@@ -61,6 +61,8 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import e2e_bench, fault_bench, imc_bench, kernels_bench
     from benchmarks import obs_bench, paper_tables, prefix_bench
     from benchmarks import scheduler_bench

@@ -18,6 +18,7 @@ import shutil
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro import checkpoint as ckpt_lib
 from repro.configs import get_arch
@@ -66,7 +67,8 @@ print("failure-free run matches exactly: recovery lost/repeated no steps")
 # on a pod this is e.g. 512 -> 256 chips; arrays are saved as full logical
 # values and re-laid-out by device_put)
 step = ckpt_lib.latest_step(CKPT)
-mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+mesh2 = jax.make_mesh((1, 1), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 tr3 = Trainer(cfg, shape, mesh2, settings,
               TrainerConfig(total_steps=12, ckpt_dir=CKPT, warmup=2))
 print(f"elastic restore at step {tr3.current_step()} onto mesh "

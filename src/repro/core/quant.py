@@ -19,11 +19,22 @@ INT4_MAX = 7        # symmetric int4: [-7, 7] (-8 reserved, keeps negation close
 INT8_MAX = 127
 
 
+def runtime_constant(c, dtype=jnp.float32) -> jax.Array:
+    """`c` as a value XLA cannot fold. Under jit, XLA rewrites x / c into
+    x * (1 / c), which rounds differently; dividing by this instead keeps
+    a jitted quotient bit-identical to the eager one (the serving engine
+    jits its weight initializer and packer, whose eager results the
+    golden tests pin, and the jitted steps quantize with the same scales
+    as the host packers)."""
+    return jax.lax.optimization_barrier(jnp.asarray(c, dtype))
+
+
 def absmax_scale(x: jax.Array, axis=None, qmax: int = INT4_MAX,
                  eps: float = 1e-8) -> jax.Array:
-    """Per-axis symmetric scale so that max|x| maps to qmax."""
+    """Per-axis symmetric scale so that max|x| maps to qmax (a true
+    division, jitted or not: see `runtime_constant`)."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    return jnp.maximum(amax, eps) / qmax
+    return jnp.maximum(amax, eps) / runtime_constant(qmax, amax.dtype)
 
 
 def quantize(x: jax.Array, scale: jax.Array, qmax: int,

@@ -19,10 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax: shard_map lives in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -19,10 +19,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax: shard_map lives in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -74,15 +71,11 @@ def pipeline_forward(mesh, stage_fn: Callable, n_micro: int,
                 nxt = jax.lax.ppermute(y, axis, perm)
                 return (nxt, outs), None
 
-            # pcast marks carries as axis-varying for the new vartype
-            # checker; absent (pre-0.5 jax) everything in shard_map is
-            # already local/varying, so it degrades to identity.
-            pcast = getattr(jax.lax, "pcast",
-                            lambda t, _axes, to: t)
-            buf0 = pcast(jnp.zeros(mb_shape, xs_l.dtype), (axis,),
-                         to="varying")
-            outs0 = pcast(jnp.zeros_like(xs_l), (axis,),
-                          to="varying")
+            # pcast marks carries as axis-varying for the vartype checker
+            buf0 = jax.lax.pcast(jnp.zeros(mb_shape, xs_l.dtype), (axis,),
+                                 to="varying")
+            outs0 = jax.lax.pcast(jnp.zeros_like(xs_l), (axis,),
+                                  to="varying")
             (_, outs), _ = jax.lax.scan(tick, (buf0, outs0),
                                         jnp.arange(n_ticks))
             # only the last stage holds valid outputs; replicate them

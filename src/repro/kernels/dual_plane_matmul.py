@@ -5,8 +5,9 @@ ONE physical uint8 buffer holds TWO int4 weight matrices — high nibble =
 static plane, low nibble = dynamic plane (e.g. the K-projection and
 V-projection of an attention layer, written by the AugmentedStore under
 its FILO ledger). The kernel reads each byte from HBM ONCE, splits the
-planes in VMEM registers (arithmetic shift for the hi nibble's sign,
-shift-left-then-right for lo), and issues two MXU dots per tile:
+planes in VMEM registers (each nibble is shifted to the top of an int32
+lane and arithmetic-shifted back, which sign-extends it), and issues two
+MXU dots per tile:
 
     y_hi = x @ dequant(hi(buf), hi_scale)
     y_lo = x @ dequant(lo(buf), lo_scale)
@@ -20,12 +21,12 @@ Blocks (bm, bk, bn) = (128, 256, 256): VMEM = bm*bk*2 + bk*bn*1 +
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.packed_kv_attention import as_bf16, nibbles
 
 DEFAULT_BM = 128
 DEFAULT_BK = 256
@@ -34,10 +35,8 @@ DEFAULT_BN = 256
 
 def _split_planes(buf: jax.Array):
     """uint8 -> (hi int4, lo int4) as bf16, sign-extended."""
-    hi = jnp.right_shift(buf.astype(jnp.int8), 4)
-    lo = jnp.right_shift(
-        jnp.left_shift(buf.astype(jnp.uint8), 4).astype(jnp.int8), 4)
-    return hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16)
+    hi, lo = nibbles(buf)
+    return as_bf16(hi), as_bf16(lo)
 
 
 def _dual_plane_kernel(x_ref, buf_ref, hs_ref, ls_ref, ohi_ref, olo_ref,
@@ -88,7 +87,7 @@ def dual_plane_matmul_pallas(x: jax.Array, buf: jax.Array,
                    jax.ShapeDtypeStruct((M, N), out_dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, buf, hi_scale, lo_scale)

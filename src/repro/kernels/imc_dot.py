@@ -207,7 +207,7 @@ def imc_dot_pallas(xq: jax.Array, xs: jax.Array, wp: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, xs, wp, scale)
@@ -242,7 +242,7 @@ def imc_dual_dot_pallas(xq: jax.Array, xs: jax.Array, buf: jax.Array,
                    jax.ShapeDtypeStruct((M, N), out_dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, xs, buf, hi_scale, lo_scale)

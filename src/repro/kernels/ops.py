@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation; on TPU they compile to
-Mosaic. `interpret=None` auto-detects.
+On a TPU the kernels compile with Mosaic; on the CPU backend they run in
+the Pallas interpreter (the kernel body runs in Python, for correctness
+checks). `_auto_interpret` holds the rule.
 """
 from __future__ import annotations
 
@@ -23,8 +23,25 @@ from repro.kernels.ternary_matmul import ternary_matmul_pallas
 
 
 def _auto_interpret(interpret):
+    """Whether a kernel call runs in the Pallas interpreter.
+
+    On a TPU every call compiles with Mosaic: interpret mode is refused
+    there, even when asked for, so a chip run cannot quietly execute a
+    kernel in Python. The CPU backend cannot run Mosaic at all; there a
+    call that does not choose runs interpreted (how the tests check the
+    kernels). Any other backend has to choose."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        if interpret:
+            raise ValueError("interpret mode was requested on a TPU; the "
+                             "kernels compile with Mosaic there")
+        return False
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        if backend != "cpu":
+            raise RuntimeError(
+                f"the Pallas kernels compile only for a TPU; on {backend!r} "
+                f"pass interpret=True to run them in the interpreter")
+        return True
     return interpret
 
 

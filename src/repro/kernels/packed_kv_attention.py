@@ -47,13 +47,37 @@ DEFAULT_BS = 512
 NEG_INF = -1e30
 
 
+def nibbles(packed: jax.Array):
+    """uint8 block -> (high, low) nibbles as sign-extended int32.
+
+    Each nibble is shifted to the top of an int32 lane and arithmetic-
+    shifted back: Mosaic has no int8 arithmetic shift."""
+    b = packed.astype(jnp.int32)
+    return (jnp.right_shift(jnp.left_shift(b, 24), 28),
+            jnp.right_shift(jnp.left_shift(b, 28), 28))
+
+
+def as_bf16(x: jax.Array) -> jax.Array:
+    """Integer block -> bf16 (through f32: Mosaic converts integers to
+    f32 only)."""
+    return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+
 def _unpack_int4_pairs(packed: jax.Array) -> jax.Array:
-    """(bs, D//2) uint8 -> (bs, D) bf16 int4 values (interleaved pairs)."""
-    hi = jnp.right_shift(packed.astype(jnp.int8), 4)
-    lo = jnp.right_shift(
-        jnp.left_shift(packed.astype(jnp.uint8), 4).astype(jnp.int8), 4)
-    w = jnp.stack([hi, lo], axis=-1)        # (bs, D//2, 2)
-    return w.reshape(packed.shape[0], -1).astype(jnp.bfloat16)
+    """(bs, D//2) uint8 -> (bs, D) bf16 int4 values (interleaved pairs).
+
+    Mosaic has no lane-interleaving reshape, so the pairs are interleaved
+    by two 0/1 selector products on the MXU: every operand is an integer
+    in [-8, 7], so they are exact."""
+    hi, lo = nibbles(packed)
+    half = packed.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+    w = (jnp.dot(as_bf16(hi), (col == 2 * row).astype(jnp.bfloat16),
+                 preferred_element_type=jnp.float32)
+         + jnp.dot(as_bf16(lo), (col == 2 * row + 1).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32))
+    return w.astype(jnp.bfloat16)
 
 
 def _load_kv_block(ref_block: jax.Array, kv_bits: int) -> jax.Array:
@@ -63,7 +87,7 @@ def _load_kv_block(ref_block: jax.Array, kv_bits: int) -> jax.Array:
     itself — no unpack, the cast is the whole "sense amplifier"."""
     if kv_bits == 4:
         return _unpack_int4_pairs(ref_block)
-    return ref_block.astype(jnp.bfloat16)
+    return as_bf16(ref_block)
 
 
 def _num_valid_blocks(length, bs: int):
@@ -190,7 +214,7 @@ def packed_kv_attention_pallas(q: jax.Array, k_packed: jax.Array,
                           kv_bits=kv_bits, debug_visits=debug_visits),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k_packed, v_packed, k_scale, v_scale)

@@ -30,9 +30,12 @@ Grid: (B, KV, maxP); B and KV are `parallel`, the page walk is
 cdiv(length, page) — no MXU/VPU work for short rows, so grid work is
 proportional to actual cache length exactly as in the contiguous kernel.
 
-TPU note: page_size is the sequence-block size; pick >= the dtype's
-sublane tile (16 for bf16, 32 for int8) on real hardware. CPU tests run
-in interpret mode where any page size goes.
+TPU note: page_size is the sequence-block size. Every block spans the
+whole of its array's last two dims (a page's rows x head_dim, or a
+page's (KV, page) scales, of which the kernel reads its head's row), so
+Mosaic accepts any page size,
+16 included, for the bf16 and the int8/uint8 arenas alike
+(tests/test_tpu_compile.py compiles pages of 16 and 32 for a v5e).
 """
 from __future__ import annotations
 
@@ -46,11 +49,23 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.packed_kv_attention import NEG_INF, _load_kv_block
 
 
+def _head_scales(ref, h) -> jax.Array:
+    """Head `h`'s row of a (1, KV, page) scale block, as a (1, page) f32
+    row vector: the score columns are scaled by it. The block holds all
+    KV heads of the page so that it spans the arena's last two dims, as
+    the TPU tiling rule asks, with no relayout of the arena. The row is
+    picked by a masked sublane sum (exact: one term plus zeros), since
+    Mosaic loads no dynamic sublane slice of a bf16 block."""
+    x = ref[0].astype(jnp.float32)                        # (KV, page)
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(rows == h, x, 0.0), axis=0, keepdims=True)
+
+
 def _paged_kernel(lens_ref, modes_ref, ni_ref, pi_ref, q_ref, kn_ref,
                   vn_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page: int, scale: float,
                   kv_bits: int):
-    b = pl.program_id(0)
+    b, h = pl.program_id(0), pl.program_id(1)
     s_step = pl.program_id(2)
     length = lens_ref[b]
     nvp = jnp.maximum(pl.cdiv(length, page), 1)   # >=1 so init/output fire
@@ -74,12 +89,12 @@ def _paged_kernel(lens_ref, modes_ref, ni_ref, pi_ref, q_ref, kn_ref,
         v_int = jnp.where(is_aug, v_aug, vn_ref[0, 0])
         # Normal pages are pre-scaled bf16: the "sense amplifier" scale
         # collapses to 1. Augmented pages dequantize on score COLUMNS.
-        one = jnp.ones((page,), jnp.float32)
-        k_scale = jnp.where(is_aug, ks_ref[0, 0].astype(jnp.float32), one)
-        v_scale = jnp.where(is_aug, vs_ref[0, 0].astype(jnp.float32), one)
+        one = jnp.ones((1, page), jnp.float32)
+        k_scale = jnp.where(is_aug, _head_scales(ks_ref, h), one)
+        v_scale = jnp.where(is_aug, _head_scales(vs_ref, h), one)
 
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = s * (k_scale * scale)[None, :]            # (Hg, page)
+        s = s * (k_scale * scale)                     # (Hg, page)
         valid = (s_step * page
                  + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)) < length
         s = jnp.where(valid, s, NEG_INF)
@@ -89,7 +104,7 @@ def _paged_kernel(lens_ref, modes_ref, ni_ref, pi_ref, q_ref, kn_ref,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                        # (Hg, page)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = (p * v_scale[None, :]).astype(jnp.bfloat16)
+        pv = (p * v_scale).astype(jnp.bfloat16)
         acc_ref[...] = (acc_ref[...] * alpha
                         + jnp.dot(pv, v_int,
                                   preferred_element_type=jnp.float32))
@@ -129,7 +144,7 @@ def paged_kv_attention_pallas(q: jax.Array, kn: jax.Array, vn: jax.Array,
         return (pi[b, s], h, 0, 0)
 
     def _pscale(b, h, s, lens, modes, ni, pi):
-        return (pi[b, s], h, 0)
+        return (pi[b, s], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, Hg, D), lambda b, h, s, *_: (b, h, 0, 0)),
@@ -137,8 +152,8 @@ def paged_kv_attention_pallas(q: jax.Array, kn: jax.Array, vn: jax.Array,
         pl.BlockSpec((1, 1, page, D), _nidx),
         pl.BlockSpec((1, 1, page, d_store), _pidx),
         pl.BlockSpec((1, 1, page, d_store), _pidx),
-        pl.BlockSpec((1, 1, page), _pscale),
-        pl.BlockSpec((1, 1, page), _pscale),
+        pl.BlockSpec((1, KV, page), _pscale),
+        pl.BlockSpec((1, KV, page), _pscale),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -154,7 +169,7 @@ def paged_kv_attention_pallas(q: jax.Array, kn: jax.Array, vn: jax.Array,
                           kv_bits=kv_bits),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, Hg, D), jnp.bfloat16),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, modes.astype(jnp.int32), normal_idx.astype(jnp.int32),
@@ -174,7 +189,7 @@ def _paged_window_kernel(starts_ref, modes_ref, ni_ref, pi_ref, q_ref,
     starts + w + 1 — pages wholly past a slot's horizon contribute
     exp(NEG_INF - m) == 0.0 in f32, a bit-exact no-op. That is the
     token-identity anchor the speculative engine's golden test pins."""
-    b = pl.program_id(0)
+    b, h = pl.program_id(0), pl.program_id(1)
     s_step = pl.program_id(2)
     max_p = pl.num_programs(2)
     start = starts_ref[b]
@@ -192,31 +207,33 @@ def _paged_window_kernel(starts_ref, modes_ref, ni_ref, pi_ref, q_ref,
 
     @pl.when(visited)
     def _compute():
-        q = q_ref[0, 0].reshape(rows, q_ref.shape[-1])     # (W*Hg, D) bf16
+        q = q_ref[0, 0]                                    # (W*Hg, D) bf16
         is_aug = modes_ref[b, s_step] == 1
         k_aug = _load_kv_block(kp_ref[0, 0], kv_bits)      # (page, D) bf16
         v_aug = _load_kv_block(vp_ref[0, 0], kv_bits)
         k = jnp.where(is_aug, k_aug, kn_ref[0, 0])
         v_int = jnp.where(is_aug, v_aug, vn_ref[0, 0])
-        one = jnp.ones((page,), jnp.float32)
-        k_scale = jnp.where(is_aug, ks_ref[0, 0].astype(jnp.float32), one)
-        v_scale = jnp.where(is_aug, vs_ref[0, 0].astype(jnp.float32), one)
+        one = jnp.ones((1, page), jnp.float32)
+        k_scale = jnp.where(is_aug, _head_scales(ks_ref, h), one)
+        v_scale = jnp.where(is_aug, _head_scales(vs_ref, h), one)
 
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = s * (k_scale * scale)[None, :]                 # (W*Hg, page)
+        s = s * (k_scale * scale)                          # (W*Hg, page)
         # causal-inside-the-window mask: score row r belongs to window
-        # slot r // Hg, whose horizon is start + slot + 1 tokens
+        # slot r // Hg, whose horizon is start + slot + 1 tokens, i.e.
+        # col - start <= r // Hg, which for integers is
+        # (col - start) * Hg <= r (no vector integer division)
         col = (s_step * page
                + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1))
-        slot = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) // hg
-        s = jnp.where(col < start + slot + 1, s, NEG_INF)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0)
+        s = jnp.where((col - start) * hg <= row, s, NEG_INF)
 
         m_prev = m_ref[...]                                # (W*Hg, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                             # (W*Hg, page)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = (p * v_scale[None, :]).astype(jnp.bfloat16)
+        pv = (p * v_scale).astype(jnp.bfloat16)
         acc_ref[...] = (acc_ref[...] * alpha
                         + jnp.dot(pv, v_int,
                                   preferred_element_type=jnp.float32))
@@ -224,8 +241,7 @@ def _paged_window_kernel(starts_ref, modes_ref, ni_ref, pi_ref, q_ref,
 
     @pl.when(s_step == nvp - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).reshape(
-            win, hg, o_ref.shape[-1]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_kv_attention_window_pallas(q: jax.Array, kn: jax.Array,
@@ -255,35 +271,38 @@ def paged_kv_attention_window_pallas(q: jax.Array, kn: jax.Array,
         return (pi[b, s], h, 0, 0)
 
     def _pscale(b, h, s, lens, modes, ni, pi):
-        return (pi[b, s], h, 0)
+        return (pi[b, s], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, W, Hg, D), lambda b, h, s, *_: (b, h, 0, 0, 0)),
+        pl.BlockSpec((1, 1, W * Hg, D), lambda b, h, s, *_: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, page, D), _nidx),
         pl.BlockSpec((1, 1, page, D), _nidx),
         pl.BlockSpec((1, 1, page, d_store), _pidx),
         pl.BlockSpec((1, 1, page, d_store), _pidx),
-        pl.BlockSpec((1, 1, page), _pscale),
-        pl.BlockSpec((1, 1, page), _pscale),
+        pl.BlockSpec((1, KV, page), _pscale),
+        pl.BlockSpec((1, KV, page), _pscale),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, KV, maxP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, W, Hg, D),
-                               lambda b, h, s, *_: (b, h, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, W * Hg, D),
+                               lambda b, h, s, *_: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((W * Hg, D), jnp.float32),
                         pltpu.VMEM((W * Hg, 1), jnp.float32),
                         pltpu.VMEM((W * Hg, 1), jnp.float32)],
     )
-    return pl.pallas_call(
+    # the W window queries share one page walk as W*Hg score rows; the
+    # flattening happens here (Mosaic cannot merge a sub-tile Hg dim)
+    out = pl.pallas_call(
         functools.partial(_paged_window_kernel, page=page, scale=scale,
                           kv_bits=kv_bits, win=W, hg=Hg),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, W, Hg, D), jnp.bfloat16),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, W * Hg, D), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(starts.astype(jnp.int32), modes.astype(jnp.int32),
       normal_idx.astype(jnp.int32), packed_idx.astype(jnp.int32),
-      q, kn, vn, kp, vp, k_scale, v_scale)
+      q.reshape(B, KV, W * Hg, D), kn, vn, kp, vp, k_scale, v_scale)
+    return out.reshape(B, KV, W, Hg, D)

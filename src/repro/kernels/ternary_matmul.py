@@ -19,12 +19,12 @@ under the ~16 MiB/core VMEM budget, leaving room for double buffering.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.packed_kv_attention import as_bf16
 
 
 DEFAULT_BM = 128
@@ -32,27 +32,34 @@ DEFAULT_BK = 512   # trits of K per step = 128 packed bytes
 DEFAULT_BN = 256
 
 
-def _unpack_2bit(wp: jax.Array, bk: int, bn: int) -> jax.Array:
-    """(bk//4, bn) uint8 -> (bk, bn) bf16 trits in {-1, 0, +1}.
+def _unpack_2bit(wp: jax.Array) -> jax.Array:
+    """(bk//4, bn) uint8 -> (bk, bn) bf16 trits in {-1, 0, +1}, DIGIT-major:
+    row t * bk//4 + i holds digit t of byte row i (K index 4 * i + t).
 
-    One broadcast shift over a unit axis extracts all four 2-bit digits
-    at once (vs four serialized shift/mask rounds)."""
-    shifts = (jnp.arange(4, dtype=jnp.uint8) * 2)[None, :, None]
-    d = jnp.bitwise_and(jnp.right_shift(wp[:, None, :], shifts),
-                        jnp.uint8(0x3))            # (bk//4, 4, bn)
-    w = d.astype(jnp.int8) - 1
-    return w.reshape(bk, bn).astype(jnp.bfloat16)
+    The four digits are stacked along sublanes rather than interleaved
+    (an interleaving reshape has no Mosaic layout); the wrapper permutes
+    the activation columns to the same order, so the dot is unchanged."""
+    b = wp.astype(jnp.int32)
+    digits = [jnp.bitwise_and(jnp.right_shift(b, 2 * t), 3) - 1
+              for t in range(4)]
+    return as_bf16(jnp.concatenate(digits, axis=0))
 
 
-def _ternary_matmul_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *,
-                           bk: int, bn: int):
+def _digit_major_columns(x: jax.Array, bk: int) -> jax.Array:
+    """Permute x's K columns inside each bk block to the digit-major order
+    `_unpack_2bit` produces: column t * bk//4 + i <- column 4 * i + t."""
+    M, K = x.shape
+    return x.reshape(M, K // bk, bk // 4, 4).swapaxes(2, 3).reshape(M, K)
+
+
+def _ternary_matmul_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_2bit(wp_ref[...], bk, bn)
+    w = _unpack_2bit(wp_ref[...])
     acc_ref[...] += jnp.dot(x_ref[...], w,
                             preferred_element_type=jnp.float32)
 
@@ -78,7 +85,7 @@ def ternary_matmul_pallas(x: jax.Array, w_packed: jax.Array,
     assert M % bm == 0 and K % bk == 0 and N % bn == 0, (M, K, N, bm, bk, bn)
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
-        functools.partial(_ternary_matmul_kernel, bk=bk, bn=bn),
+        _ternary_matmul_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -88,7 +95,7 @@ def ternary_matmul_pallas(x: jax.Array, w_packed: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w_packed, scale)
+    )(_digit_major_columns(x, bk), w_packed, scale)

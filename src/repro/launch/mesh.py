@@ -12,28 +12,33 @@ jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """`jax.make_mesh` with Auto axes: the sharding rules constrain
+    activations with `with_sharding_constraint`, which under the default
+    Explicit axes would only assert."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_local_mesh() -> jax.sharding.Mesh:
-    """Whatever devices exist (CPU smoke tests: 1 device)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+def make_local_mesh(devices=None) -> jax.sharding.Mesh:
+    """A ("data", "model") mesh over `devices` (default: every local
+    device; CPU smoke tests have 1)."""
+    devices = list(jax.devices() if devices is None else devices)
+    return _auto_mesh((len(devices), 1), ("data", "model"), devices)
 
 
 def mesh_context(mesh: jax.sharding.Mesh):
-    """`jax.set_mesh(mesh)` where available, else the Mesh's own context
-    manager (pre-0.5 jax has no `set_mesh`; entering the Mesh sets the
-    global mesh for sharding resolution the same way)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Context that makes `mesh` the one sharding rules resolve against."""
+    return jax.set_mesh(mesh)
 
 
 # TPU v5e-class hardware constants for the roofline (per chip)

@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import ArrayFleet, Request, make_serving
 
 
@@ -100,6 +101,7 @@ def main():
                          "to every synthetic request (the prefix-cache "
                          "hit workload)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.quant import runtime_constant
+
 
 @dataclasses.dataclass(frozen=True)
 class PSpec:
@@ -60,7 +62,8 @@ def init_params(tree, key, dtype_override=None):
                                      if len(l.shape) > 1 else (0,))
             for d in dims:
                 fan *= l.shape[d]
-            w = jax.random.normal(k, l.shape, jnp.float32) / np.sqrt(max(fan, 1))
+            w = (jax.random.normal(k, l.shape, jnp.float32)
+                 / runtime_constant(np.sqrt(max(fan, 1))))
             out.append(w.astype(dt))
     return jax.tree.unflatten(treedef, out)
 

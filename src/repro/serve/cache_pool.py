@@ -60,6 +60,17 @@ from repro.models import layers as L
 POOL_MODES = ("normal-only", "augment-on-pressure", "always-augmented")
 
 
+def to_device(x) -> jax.Array:
+    """Host array -> device array through a private copy.
+
+    The host-to-device transfer may read its source after the call has
+    returned, so the serving stack never hands it its live host
+    bookkeeping (positions, page tables, slot modes): those are mutated
+    right after a dispatch, and a mutation racing the transfer changes
+    what the device step reads."""
+    return jnp.asarray(np.array(x, copy=True))
+
+
 def resolve_pool_mode(cfg: ModelConfig) -> str:
     """Validated `cfg.amc.resolved_pool_mode` (auto follows kv_mode)."""
     mode = cfg.amc.resolved_pool_mode
@@ -1090,20 +1101,20 @@ class PagedKVPool:
             lastp = np.where(live & (md[:, s] == 1), pt[:, s], lastp)
             nidx[:, s], pidx[:, s] = lastn, lastp
         B, maxP = self.max_batch, self.max_pages
-        tables = {"page_table": jnp.asarray(pt[:B, :maxP]),
-                  "page_modes": jnp.asarray(md[:B, :maxP]),
-                  "normal_idx": jnp.asarray(nidx[:B, :maxP]),
-                  "packed_idx": jnp.asarray(pidx[:B, :maxP])}
+        tables = {"page_table": to_device(pt[:B, :maxP]),
+                  "page_modes": to_device(md[:B, :maxP]),
+                  "normal_idx": to_device(nidx[:B, :maxP]),
+                  "packed_idx": to_device(pidx[:B, :maxP])}
         if self.prefix_pages:
             Pc = self.prefix_pages
             clen = np.where(self.allocated[B:, :Pc].any(axis=1),
                             self.prefix_tokens, 0).astype(np.int32)
             tables.update({
-                "cross_table": jnp.asarray(pt[B:, :Pc]),
-                "cross_modes": jnp.asarray(md[B:, :Pc]),
-                "cross_normal_idx": jnp.asarray(nidx[B:, :Pc]),
-                "cross_packed_idx": jnp.asarray(pidx[B:, :Pc]),
-                "cross_lengths": jnp.asarray(clen),
+                "cross_table": to_device(pt[B:, :Pc]),
+                "cross_modes": to_device(md[B:, :Pc]),
+                "cross_normal_idx": to_device(nidx[B:, :Pc]),
+                "cross_packed_idx": to_device(pidx[B:, :Pc]),
+                "cross_lengths": to_device(clen),
             })
         self._tables_cache = tables
         return self._tables_cache

@@ -34,6 +34,7 @@ accounted separately (`stats()["pool"]["maintenance_dispatches"]`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Optional
@@ -41,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import amc
@@ -55,6 +57,7 @@ from repro.models import model as M
 from repro.models.params import init_params, is_pspec
 from repro.obs import hooks as obs_hooks
 from repro.serve import state_store
+from repro.serve.cache_pool import to_device
 from repro.serve.prefix import PrefixIndex
 from repro.serve.scheduler import QueueEntry, Scheduler
 
@@ -93,6 +96,15 @@ def _resolve_draft_cfg(cfg: ModelConfig) -> ModelConfig:
             f"unknown spec_draft_impl {impl!r} (expected dequant | dense "
             f"| packed | imc1/imc4/imc8 | same)")
     return dataclasses.replace(cfg, amc=amc_cfg)
+
+
+def init_dense_params(dense_cfg: ModelConfig, seed: int, sharding):
+    """Seeded dense weights of `dense_cfg`, made on `sharding`'s devices by
+    one jitted program: each leaf is drawn and cast in place, with no
+    eager f32 copy of a whole stacked weight."""
+    tree = M.abstract_params(dense_cfg)
+    return jax.jit(lambda key: init_params(tree, key),
+                   out_shardings=sharding)(jax.random.PRNGKey(seed))
 
 
 def _abstract_bytes(tree) -> int:
@@ -187,19 +199,28 @@ class ServeEngine:
         self.rules = Rules.make(mesh, cfg, shape)
         dense_cfg = dataclasses.replace(
             cfg, amc=dataclasses.replace(cfg.amc, weight_mode="normal"))
-        with mesh_context(mesh):
+        # weights and decode state live on this engine's own mesh (an
+        # ArrayFleet gives every array its own devices), replicated over
+        # all of its devices: each holds the whole packed tree, and only
+        # the step's activations follow the Rules' head sharding
+        on_mesh = NamedSharding(mesh, PartitionSpec())
+        with mesh_context(mesh), jax.default_device(mesh.devices.flat[0]):
             if params is None:
-                params = init_params(M.abstract_params(dense_cfg),
-                                     jax.random.PRNGKey(seed))
+                params = init_dense_params(dense_cfg, seed, on_mesh)
             # pack the matmul weights into augmented storage (no-op for
-            # weight_mode="normal", already-packed trees, other families)
-            self.params = augment.augment_params(cfg, params)
+            # weight_mode="normal", already-packed trees, other families);
+            # jitted, so the packing streams each weight once instead of
+            # holding eager f32 copies of it
+            self.params = jax.jit(
+                functools.partial(augment.augment_params, cfg),
+                out_shardings=on_mesh)(jax.device_put(params, on_mesh))
             self.store = state_store.make_store(
                 cfg, max_batch=max_batch, max_seq=max_seq,
                 budget_bytes=pool_budget_bytes,
                 pages_normal=pool_pages_normal,
                 pages_packed=pool_pages_packed,
                 retention_steps=retention_steps)
+            self.store.state = jax.device_put(self.store.state, on_mesh)
         # observability facade (obs/): Null unless a plane is switched on,
         # so every hook below is a constant no-op on the default path.
         # A pre-built facade may be injected (`obs=`): the ArrayFleet
@@ -679,9 +700,9 @@ class ServeEngine:
             with self.obs.chunk_span(rid, n):
                 logits = self._dispatch(
                     self._prefill,
-                    {"tokens": jnp.asarray(tok),
-                     "positions": jnp.asarray(positions),
-                     "write_mask": jnp.asarray(write_mask)})
+                    {"tokens": to_device(tok),
+                     "positions": to_device(positions),
+                     "write_mask": to_device(write_mask)})
             self.prefill_dispatch_count += 1
             self._account_dispatch(np.array([slot]), n,
                                    np.array([p + n]), np.array([p]))
@@ -712,9 +733,9 @@ class ServeEngine:
         tokens[slot, 0] = token
         mask = np.zeros(self.max_batch, bool)
         mask[slot] = True
-        batch = {"tokens": jnp.asarray(tokens),
-                 "positions": jnp.asarray(self.positions),
-                 "write_mask": jnp.asarray(mask)}
+        batch = {"tokens": to_device(tokens),
+                 "positions": to_device(self.positions),
+                 "write_mask": to_device(mask)}
         logits = self._dispatch(self._decode, batch)
         self.store.note_token_writes(np.array([slot]),
                                      np.array([self.positions[slot]]),
@@ -796,9 +817,9 @@ class ServeEngine:
         self._ensure_decode_capacity()
         tokens = np.where(self.active, self.last_token, 0
                           ).astype(np.int32)[:, None]
-        batch = {"tokens": jnp.asarray(tokens),
-                 "positions": jnp.asarray(self.positions),
-                 "write_mask": jnp.asarray(self.active)}
+        batch = {"tokens": to_device(tokens),
+                 "positions": to_device(self.positions),
+                 "write_mask": to_device(self.active)}
         logits = self._dispatch(self._decode, batch)
         rows = np.flatnonzero(self.active)
         self._account_dispatch(rows, 1, self.positions[rows] + 1,
@@ -878,9 +899,9 @@ class ServeEngine:
                 pos_i = np.minimum(self.positions + i, self.max_seq - 1)
                 lg = self._dispatch(
                     self._draft_decode,
-                    {"tokens": jnp.asarray(toks[:, i:i + 1]),
-                     "positions": jnp.asarray(pos_i),
-                     "write_mask": jnp.asarray(wmask2d[:, i])})
+                    {"tokens": to_device(toks[:, i:i + 1]),
+                     "positions": to_device(pos_i),
+                     "write_mask": to_device(wmask2d[:, i])})
                 self.energy_ledger.add(
                     imc_energy.decode_matmul_events(self._draft_cfg,
                                                     int(rows.size)),
@@ -895,9 +916,9 @@ class ServeEngine:
         with self.obs.phase_span("spec_verify", k=W):
             logits = self._dispatch(
                 self._verify,
-                {"tokens": jnp.asarray(toks),
-                 "positions": jnp.asarray(self.positions),
-                 "write_mask": jnp.asarray(wmask2d)})
+                {"tokens": to_device(toks),
+                 "positions": to_device(self.positions),
+                 "write_mask": to_device(wmask2d)})
         self._spec_stats["verify_dispatches"] += 1
         self._spec_stats["spec_rounds"] += 1
         self._account_dispatch(rows, W, self.positions[rows] + cap[rows],

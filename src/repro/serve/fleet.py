@@ -6,11 +6,11 @@ one full `ServeEngine` each, so every array owns its OWN byte budget,
 state store (paged KV pool and/or slab pool), refresh clock (`step_idx`),
 fault domain (FaultModel + per-array Supervisor) and energy/IMC ledger —
 over a partition of the jax mesh (serve/placement.py): contiguous device
-groups when devices >= arrays, with each array's projections sharded
-tensor-parallel over its own "model" axis by the distributed/sharding
-Rules (replicated where head counts don't divide); round-robin device
-sharing otherwise (the `jax.sharding`-over-host case — on one CPU device
-every logical array shares it).
+groups when devices >= arrays, with each array's weights and store
+replicated over its own group and the step's heads split over its
+"model" axis by the distributed/sharding Rules where counts divide;
+round-robin device sharing otherwise (the `jax.sharding`-over-host case
+— on one CPU device every logical array shares it).
 
 Placement: a fleet-level `PlacementPolicy` (least-loaded /
 budget-headroom / affinity) admits each request onto one array. Between
@@ -39,17 +39,15 @@ import dataclasses
 import time
 from typing import Optional
 
-import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.distributed.fault import SimulatedFailure, Supervisor
-from repro.launch.mesh import make_local_mesh, mesh_context
-from repro.models import model as M
-from repro.models.params import init_params
+from repro.launch.mesh import make_local_mesh
 from repro.obs import hooks as obs_hooks
 from repro.obs.metrics import MetricsRegistry
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import Request, ServeEngine, init_dense_params
 from repro.serve.placement import (ArrayView, make_array_meshes,
                                    make_policy)
 
@@ -78,9 +76,9 @@ class ArrayFleet:
         dense_cfg = dataclasses.replace(
             cfg, amc=dataclasses.replace(cfg.amc, weight_mode="normal"))
         if params is None:
-            with mesh_context(self.meshes[0]):
-                params = init_params(M.abstract_params(dense_cfg),
-                                     jax.random.PRNGKey(seed))
+            params = init_dense_params(
+                dense_cfg, seed, NamedSharding(self.meshes[0],
+                                               PartitionSpec()))
         # obs: per-array facades on one shared clock epoch + one shared
         # metrics registry, each tracing on its own pid ("array N" lane)
         trace_on = cfg.amc.trace if trace is None else trace

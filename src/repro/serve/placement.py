@@ -18,9 +18,9 @@ pieces the fleet composes:
                           back to least-loaded when the preferred array
                           cannot admit right now
   * device partitioning: N arrays over the jax mesh — contiguous device
-    groups when devices >= arrays (each array's projections then shard
-    tensor-parallel over its own "model" axis via distributed/sharding
-    Rules, replicating where head counts don't divide), round-robin
+    groups when devices >= arrays (each array's weights and store are
+    replicated over its own group; the distributed/sharding Rules split
+    the step's heads over its "model" axis where counts divide), round-robin
     device *sharing* otherwise (the `jax.sharding`-over-host case: on one
     CPU device every array is a logical array on the same device).
 
@@ -182,10 +182,10 @@ def partition_devices(devices: list, num_arrays: int) -> list[list]:
 def make_array_meshes(num_arrays: int, mesh=None) -> list:
     """One jax mesh per array over a partition of `mesh`'s devices (the
     process-global devices when no mesh is given). Each array's devices
-    land on the "model" axis: within an array the sharding Rules resolve
-    head-sharded tensor-parallel projections where counts divide and
-    replicate otherwise; across arrays the fleet is trivially parallel
-    (each array serves its own requests)."""
+    land on the "model" axis: within an array the weights and store are
+    replicated over them (ServeEngine) and the sharding Rules split the
+    step's heads where counts divide; across arrays the fleet is
+    trivially parallel (each array serves its own requests)."""
     import jax
     from jax.sharding import Mesh
     devs = (list(np.asarray(mesh.devices).flat) if mesh is not None

@@ -63,7 +63,8 @@ from repro.configs.base import ModelConfig
 from repro.core import faults as F
 from repro.core import quant
 from repro.core.retention import RefreshPolicy
-from repro.serve.cache_pool import PagedKVPool, resolve_pool_mode
+from repro.serve.cache_pool import (PagedKVPool, resolve_pool_mode,
+                                    to_device)
 
 
 def _keystr(path) -> str:
@@ -785,7 +786,7 @@ class AugmentedStatePool:
             return {}
         if self._tables_cache is None:
             self._tables_cache = {
-                self.table_key: jnp.asarray(self.slot_mode)}
+                self.table_key: to_device(self.slot_mode)}
         return self._tables_cache
 
     # -- array event accounting ------------------------------------------------

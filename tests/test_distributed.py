@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_arch, get_shape
 from repro.launch.mesh import mesh_context
@@ -110,7 +111,8 @@ def test_param_and_opt_spec_trees_align():
 
 def test_compressed_allreduce_error_feedback():
     from repro.distributed.collectives import make_compressed_grad_allreduce
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = jax.make_mesh((1, 1), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     # pod axis size 1 -> compression disabled (returns None)
     assert make_compressed_grad_allreduce(mesh, "pod") is None
 
@@ -133,7 +135,7 @@ def test_compressed_quantization_bounded_and_unbiased():
 
 def test_pipeline_single_stage_equals_direct():
     from repro.distributed.pipeline import pipeline_forward
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     w = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 8))
 
     def stage(p, x):

@@ -8,6 +8,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import mesh_context
@@ -68,7 +69,7 @@ def test_analyzer_nested_loops():
 
 
 def test_analyzer_counts_collectives():
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):

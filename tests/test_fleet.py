@@ -5,6 +5,11 @@ cross-array PR-7 guarantee), byte-budget/no-loss placement invariants
 (hypothesis), and per-array trace lanes merging into one valid trace."""
 import dataclasses
 import itertools
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -165,6 +170,71 @@ def test_fleet_token_identity_vs_single_array(arch):
     # both arrays actually served (least-loaded spreads 4 reqs 2/2)
     assert st_["placements_per_array"] == [2, 2]
     assert st_["peak_concurrency"] >= 3
+
+
+# ---------------------------------------------------------------------------
+# placement: with one device per array, each array's weights and store
+# live on its own device, and the fleet still matches one array
+# ---------------------------------------------------------------------------
+
+_FOUR_DEVICE_FLEET = textwrap.dedent("""
+    import json
+
+    import jax
+    from repro.configs import get_arch
+    from repro.launch.mesh import make_local_mesh
+    from repro.serve import ArrayFleet, Request, make_serving
+    import numpy as np
+
+    devs = jax.devices()
+    assert len(devs) == 4, devs
+
+    def held(tree):
+        return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+    cfg = get_arch("granite-3-2b").reduced()
+    rng = np.random.default_rng(0)
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab, size=int(n))
+                    .astype(np.int32), max_new_tokens=4, id=i)
+            for i, n in enumerate(rng.integers(4, 12, size=6))]
+    kw = dict(max_batch=2, max_seq=32, seed=1)
+    single = make_serving(cfg, make_local_mesh(devs[2:3]), **kw)
+    assert held(single.params) == held(single.store.state) == {devs[2]}
+    want = single.generate([Request(prompt=r.prompt, max_new_tokens=4,
+                                    id=r.id) for r in reqs])
+    fleet = make_serving(cfg, make_local_mesh(devs), num_arrays=4, **kw)
+    assert isinstance(fleet, ArrayFleet)
+    owned = []
+    for aid, eng in enumerate(fleet.engines):
+        own = set(eng.mesh.devices.flat)   # the mesh may reorder devices
+        assert len(own) == 1, own
+        assert held(eng.params) == own, (aid, held(eng.params))
+        assert held(eng.store.state) == own, aid
+        owned += own
+    assert sorted(owned, key=str) == sorted(devs, key=str), owned
+    got = fleet.generate(reqs)
+    assert got == want, (got, want)
+    print(json.dumps(fleet.stats()["fleet"]["placements_per_array"]))
+""")
+
+
+def test_fleet_arrays_hold_their_own_devices():
+    """Four host devices in a child process (the device count is fixed
+    when JAX starts, and this test process keeps one): an engine built
+    on one device holds its weights and store there, and a fleet of four
+    arrays puts each array wholly on its own device, token-identical to
+    one array."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.path.join(repo, "src"),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    proc = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_FLEET],
+                          env=env, capture_output=True, text=True,
+                          timeout=600, cwd=repo)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-3000:]
+    # least-loaded spreads the six requests over all four arrays
+    per_array = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert len(per_array) == 4 and min(per_array) >= 1, per_array
 
 
 # ---------------------------------------------------------------------------
