@@ -1,0 +1,2 @@
+"""% of the traced window in which the device ran no operation."""
+from readers import idle_share as read  # noqa: F401
