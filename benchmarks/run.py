@@ -8,8 +8,7 @@ sweep), BENCH_paper_tables.json (the Tables I-VI analog rows, structured)
 BENCH_imc.json (storage matrix x activation precision: modeled
 energy/token + throughput), BENCH_fault.json (retention-fault chaos
 sweep: injection rates x recovery outcomes, with token identity to the
-fault-free run asserted), BENCH_obs.json (observability overhead vs
-the disabled Null facade + trace/metrics cross-validation) and
+fault-free run asserted) and
 BENCH_prefix.json (shared-prefix page reuse: prefill dispatches saved,
 hit rate, bytes shared, with decode token identity to the
 sharing-disabled run asserted) so the serving perf trajectory is
@@ -64,17 +63,11 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import e2e_bench, fault_bench, imc_bench, kernels_bench
-    from benchmarks import obs_bench, paper_tables, prefix_bench
+    from benchmarks import paper_tables, prefix_bench
     from benchmarks import scheduler_bench
     scheduler_run = functools.partial(scheduler_bench.run_all,
                                       num_arrays=tuple(args.num_arrays))
-    # the obs emitter measures a ~1% effect against run-to-run noise, so
-    # it goes FIRST: after minutes of heavy sweeps the machine is hot
-    # (frequency/cache state) and the measurement floor degrades
     sections = (
-        ("BENCH_obs.json",
-         "observability overhead + trace/metrics cross-validation",
-         obs_bench.run_all),
         ("BENCH_paper_tables.json", "paper tables I-VI analogs",
          paper_tables.run_all),
         ("BENCH_kernels.json", "pallas kernels (bytes/roofline)",

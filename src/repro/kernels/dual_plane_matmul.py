@@ -90,4 +90,5 @@ def dual_plane_matmul_pallas(x: jax.Array, buf: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dual_plane_matmul",
     )(x, buf, hi_scale, lo_scale)

@@ -172,6 +172,7 @@ def paged_kv_attention_pallas(q: jax.Array, kn: jax.Array, vn: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_kv_attention",
     )(lengths, modes.astype(jnp.int32), normal_idx.astype(jnp.int32),
       packed_idx.astype(jnp.int32), q, kn, vn, kp, vp, k_scale, v_scale)
 
@@ -302,6 +303,7 @@ def paged_kv_attention_window_pallas(q: jax.Array, kn: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_kv_attention_window",
     )(starts.astype(jnp.int32), modes.astype(jnp.int32),
       normal_idx.astype(jnp.int32), packed_idx.astype(jnp.int32),
       q.reshape(B, KV, W * Hg, D), kn, vn, kp, vp, k_scale, v_scale)

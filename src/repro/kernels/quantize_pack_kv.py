@@ -158,6 +158,7 @@ def quantize_pack_kv_pallas(kv: jax.Array, valid=None, *,
             out_shape=out_shape + [jax.ShapeDtypeStruct((N, 1), jnp.int32)],
             compiler_params=params,
             interpret=interpret,
+            name="quantize_pack_kv",
         )(kv)
         return p, s, jax.lax.bitcast_convert_type(w, jnp.uint32)
     if valid is None:
@@ -169,6 +170,7 @@ def quantize_pack_kv_pallas(kv: jax.Array, valid=None, *,
             out_shape=out_shape,
             compiler_params=params,
             interpret=interpret,
+            name="quantize_pack_kv",
         )(kv)
     assert valid.shape == (N, 1), (valid.shape, N)
     return pl.pallas_call(
@@ -180,4 +182,5 @@ def quantize_pack_kv_pallas(kv: jax.Array, valid=None, *,
         out_shape=out_shape,
         compiler_params=params,
         interpret=interpret,
+        name="quantize_pack_kv",
     )(kv, valid.astype(jnp.int32))

@@ -98,4 +98,5 @@ def ternary_matmul_pallas(x: jax.Array, w_packed: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ternary_matmul",
     )(_digit_major_columns(x, bk), w_packed, scale)

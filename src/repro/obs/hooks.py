@@ -14,25 +14,33 @@ engine/scheduler/refresh/fault machinery — DESIGN.md SS12):
                  -> first_token(i)/token instants -> complete(i)
                  with preempt/heal hops re-opening [queue] on the SAME
                  lane (request-id continuity across requeues)
-  engine lane    [step [admit] [spec_draft] [spec_verify]] per decode
-                 round, plus counter tracks (mode mix, occupancy, queue)
+  engine lane    [step [admit [prefill [prefill_chunk]*]] [refresh]
+                 [capacity] [dispatch | spec_draft, spec_verify]
+                 [readback] [bookkeeping]] per decode round, plus counter
+                 tracks (mode mix, occupancy, queue)
   refresh lane   refresh_pass / augment / promote / restamp instants
   fault lane     [fault_pass] spans, inject/detect/heal/fail instants
+
+Every span the facade returns — Null facade included — is also a
+`jax.profiler.TraceAnnotation` named `engine.<phase>` (trace.annotate),
+so a profiler capture shows the engine's phases on the device trace's
+clock. Arguments: `step` {step, kind, rows, admitted}, `admit`
+{admitted, prompt_tokens}, `prefill` and `prefill_chunk` {tokens}.
 
 Latency metrics (log-bucketed histograms, seconds): ttft_s (enqueue ->
 first emitted token), queue_wait_s (enqueue/requeue -> admit),
 inter_token_s (per-token gap between emissions), step_wall_s (host wall
-per engine step), prefill_chunk_s, request_latency_s.
+per engine step), request_latency_s.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Callable, Optional
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import (ENGINE_TRACK, FAULT_TRACK, REFRESH_TRACK,
-                             SCHED_TRACK, NullTracer, Tracer, _NULL_CTX)
+                             SCHED_TRACK, NullTracer, PhaseSpan, Tracer,
+                             annotate)
 
 
 class _Req:
@@ -86,6 +94,14 @@ class EngineObs:
     def _now(self) -> float:
         return self._clock()
 
+    def _span(self, tid: int, name: str, **args):
+        """Engine phase `name`: always a profiler annotation, and a
+        Chrome-JSON span on lane `tid` when that plane is on."""
+        ann = annotate(name, **args)
+        if not self.trace_on:
+            return ann
+        return PhaseSpan(ann, self.tracer.span(tid, name, **args))
+
     # -- request lifecycle ------------------------------------------------------
 
     def on_enqueue(self, rid: int, prompt_len: int, max_new: int,
@@ -117,17 +133,13 @@ class EngineObs:
 
     def prefill_span(self, rid: Optional[int], n_tokens: int):
         tid = (self._reqs[rid].tid if rid in self._reqs else ENGINE_TRACK)
-        return self.tracer.span(tid, "prefill", tokens=n_tokens)
+        return self._span(tid, "prefill", tokens=n_tokens)
 
-    @contextlib.contextmanager
     def chunk_span(self, rid: Optional[int], n_tokens: int):
-        """One chunked-prefill dispatch (async: host dispatch time) —
-        traced as a span AND observed into the prefill_chunk_s histogram."""
+        """One chunked-prefill dispatch (async: its host dispatch time;
+        the chunk's device time is in the profiler's device trace)."""
         tid = (self._reqs[rid].tid if rid in self._reqs else ENGINE_TRACK)
-        t0 = self._now()
-        with self.tracer.span(tid, "prefill_chunk", tokens=n_tokens):
-            yield
-        self.metrics.observe("prefill_chunk_s", self._now() - t0)
+        return self._span(tid, "prefill_chunk", tokens=n_tokens)
 
     def on_tokens(self, rid: int, n: int, step: int) -> None:
         """`n` tokens of request `rid` were emitted at this instant
@@ -208,10 +220,10 @@ class EngineObs:
     # -- engine phases ----------------------------------------------------------
 
     def step_span(self, step: int, kind: str):
-        return self.tracer.span(ENGINE_TRACK, "step", step=step, kind=kind)
+        return self._span(ENGINE_TRACK, "step", step=step, kind=kind)
 
     def phase_span(self, name: str, **args):
-        return self.tracer.span(ENGINE_TRACK, name, **args)
+        return self._span(ENGINE_TRACK, name, **args)
 
     def on_step_done(self, step: int, dt_s: float) -> None:
         self._h_step.observe(dt_s)
@@ -261,7 +273,7 @@ class EngineObs:
     # -- faults / healing --------------------------------------------------------
 
     def fault_span(self, step: int):
-        return self.tracer.span(FAULT_TRACK, "fault_pass", step=step)
+        return self._span(FAULT_TRACK, "fault_pass", step=step)
 
     def on_fault(self, kind: str, detail: str, step: int) -> None:
         """inject | detect | heal_scrub | heal_recompute | uncorrectable
@@ -328,11 +340,11 @@ class EngineObs:
 
 
 class NullEngineObs:
-    """Disabled observability: every hook is a constant no-op (shared
-    nullcontext for the span sites), so the instrumented engine pays one
-    attribute lookup + empty call per hook — unmeasurable against a
-    device dispatch. `make_engine_obs` returns this unless a plane is
-    switched on."""
+    """Disabled recording planes: every hook is a constant no-op and every
+    span site is the bare profiler annotation (under a microsecond when
+    no capture runs), so the instrumented engine pays one attribute
+    lookup + call per hook — unmeasurable against a device dispatch.
+    `make_engine_obs` returns this unless a plane is switched on."""
 
     enabled = False
     trace_on = False
@@ -345,10 +357,10 @@ class NullEngineObs:
         pass
 
     def prefill_span(self, rid, n_tokens):
-        return _NULL_CTX
+        return annotate("prefill", tokens=n_tokens)
 
     def chunk_span(self, rid, n_tokens):
-        return _NULL_CTX
+        return annotate("prefill_chunk", tokens=n_tokens)
 
     def on_tokens(self, rid, n, step):
         pass
@@ -366,10 +378,10 @@ class NullEngineObs:
         pass
 
     def step_span(self, step, kind):
-        return _NULL_CTX
+        return annotate("step", step=step, kind=kind)
 
     def phase_span(self, name, **args):
-        return _NULL_CTX
+        return annotate(name, **args)
 
     def on_step_done(self, step, dt_s):
         pass
@@ -393,7 +405,7 @@ class NullEngineObs:
         pass
 
     def fault_span(self, step):
-        return _NULL_CTX
+        return annotate("fault_pass", step=step)
 
     def on_fault(self, kind, detail, step):
         pass

@@ -184,7 +184,7 @@ class MetricsRegistry:
         }
 
     def dump_timeseries(self) -> dict:
-        """Full sampled timelines (BENCH_obs / offline analysis)."""
+        """Full sampled timelines (offline analysis)."""
         return {k: list(s.samples) for k, s in sorted(self.series.items())}
 
     # -- Prometheus text exposition -------------------------------------------

@@ -17,20 +17,31 @@ step loop, the scheduler, the refresh clock and the fault/heal machinery,
 plus one track per request (`REQ_TRACK_BASE + id`) so a request's whole
 life — including preempt/requeue hops between rows — reads as one
 horizontal lane in perfetto. `NullTracer` is the zero-overhead disabled
-mode: every method is a constant-return no-op and the engine shares one
-`nullcontext` for its span sites.
+mode: every method is a constant-return no-op (one shared `nullcontext`
+for its spans).
 
 Timestamps are host-side `perf_counter` microseconds from the tracer's
 construction. Dispatches are asynchronous, so a dispatch span measures
 host-side dispatch+bookkeeping time; device compute is only observed
 where the engine genuinely blocks (argmax readback) — documented, not
 hidden.
+
+The engine's phase spans have a second sink that is always on:
+`annotate` opens a `jax.profiler.TraceAnnotation` named
+`engine.<phase>`, which any `jax.profiler` capture records on the host
+plane beside the device's operations, on the same clock, with the span's
+arguments as event stats. With no capture running an annotation costs
+under a microsecond; `PhaseSpan` writes one site to both sinks when the
+Chrome-JSON plane is on.
 """
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Optional
+from typing import TYPE_CHECKING, Optional
+
+if TYPE_CHECKING:
+    from jax.profiler import TraceAnnotation
 
 # fixed tracks (tid); request tracks live at REQ_TRACK_BASE + request id
 ENGINE_TRACK = 0
@@ -47,6 +58,22 @@ TRACK_NAMES = {
 }
 
 _NULL_CTX = contextlib.nullcontext()
+
+# profiler-trace name of an engine phase: PROFILER_PREFIX + phase
+PROFILER_PREFIX = "engine."
+
+# jax.profiler.TraceAnnotation, bound on the first `annotate`: importing
+# this module (and the trace validator beside it) needs no JAX
+_Annotation = None
+
+
+def annotate(name: str, **args) -> TraceAnnotation:
+    """The profiler annotation of engine phase `name` (`engine.<name>`);
+    more arguments can be added before it closes (`set_metadata`)."""
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation as _Annotation
+    return _Annotation(PROFILER_PREFIX + name, **args)
 
 
 class _LexSpan:
@@ -72,6 +99,34 @@ class _LexSpan:
                           "pid": tr.pid, "tid": self._tid,
                           "args": self._args})
         return False
+
+    def set_metadata(self, **args) -> None:
+        """Add arguments known only once the span's work is done."""
+        self._args = {**self._args, **args}
+
+
+class PhaseSpan:
+    """One engine phase written to both sinks: the profiler annotation
+    and the Chrome-JSON span (entered inside it, closed before it)."""
+
+    __slots__ = ("_ann", "_lex")
+
+    def __init__(self, ann: TraceAnnotation, lex: _LexSpan):
+        self._ann, self._lex = ann, lex
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._lex.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._lex.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._lex.set_metadata(**args)
 
 
 class Tracer:

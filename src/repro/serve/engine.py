@@ -314,6 +314,7 @@ class ServeEngine:
         self.outputs: dict[int, list[int]] = {}
         self.dispatch_count = 0   # jitted device dispatches (prefill+decode)
         self.step_idx = 0         # decode-step clock (retention time base)
+        self._admitted_since_step = 0   # engine.step's `admitted` argument
         # array-level event/energy ledger (imc/energy.py): weight-side
         # events follow cfg.amc.matmul_impl, state-side events follow the
         # per-page / per-slab mode (Normal storage costs 6T accesses,
@@ -458,24 +459,29 @@ class ServeEngine:
         """Admission pass: move queued requests into free rows while both
         a row and store capacity exist. FIFO, head-of-line."""
         admitted: dict[int, int] = {}
-        while True:
-            free = np.flatnonzero(~self.active)
-            if free.size == 0:
-                break
-            row = int(free[0])
-            entry = self.scheduler.pop_admittable(self.step_idx)
-            if entry is None:
-                break
-            shared = self._prefix_match(entry)
-            if not self.scheduler.admit(row, len(entry.prompt),
-                                        self.step_idx,
-                                        shared=(None if shared is None else
-                                                (shared[0].row, shared[1]))):
-                # can_admit_tokens raced a concurrent change; requeue
-                self.scheduler.enqueue(entry, front=True)
-                break
-            self._start_row(row, entry, shared=shared)
-            admitted[entry.req.id] = row
+        prompt_tokens = 0
+        with self.obs.phase_span("admit") as span:
+            while True:
+                free = np.flatnonzero(~self.active)
+                if free.size == 0:
+                    break
+                row = int(free[0])
+                entry = self.scheduler.pop_admittable(self.step_idx)
+                if entry is None:
+                    break
+                shared = self._prefix_match(entry)
+                if not self.scheduler.admit(
+                        row, len(entry.prompt), self.step_idx,
+                        shared=(None if shared is None else
+                                (shared[0].row, shared[1]))):
+                    # can_admit_tokens raced a concurrent change; requeue
+                    self.scheduler.enqueue(entry, front=True)
+                    break
+                prompt_tokens += self._start_row(row, entry, shared=shared)
+                admitted[entry.req.id] = row
+            span.set_metadata(admitted=len(admitted),
+                              prompt_tokens=prompt_tokens)
+        self._admitted_since_step += len(admitted)
         return admitted
 
     def _prefix_match(self, entry: QueueEntry):
@@ -494,7 +500,9 @@ class ServeEngine:
             return None
         return e, m
 
-    def _start_row(self, row: int, entry: QueueEntry, shared=None) -> None:
+    def _start_row(self, row: int, entry: QueueEntry, shared=None) -> int:
+        """Seat `entry` in `row` and prefill its prompt; returns the
+        number of prompt tokens prefilled (prefix-cache hits skip some)."""
         self.active[row] = True
         self.slot_req[row] = entry.req
         self._slot_entry[row] = entry
@@ -525,6 +533,7 @@ class ServeEngine:
         if shared is None:
             self._register_prefix(row, fed)
         self.last_token[row] = int(prompt[-1])
+        return max(int(fed.size) - m, 0)
 
     def _register_prefix(self, row: int, fed: np.ndarray) -> None:
         """Cache the freshly prefilled prompt's full pages as a prefix
@@ -788,21 +797,25 @@ class ServeEngine:
                 return {}
         t0 = time.perf_counter()
         with self.obs.step_span(self.step_idx,
-                                "spec" if self._spec else "decode"):
-            with self.obs.phase_span("admit"):
-                self._admit()
+                                "spec" if self._spec else "decode") as span:
+            self._admit()
             if self._fault_model is not None:
                 # inject -> detect -> heal BEFORE refresh and dispatch, so
                 # corrupted storage is never read, refreshed or promoted
                 with self.obs.fault_span(self.step_idx):
                     self._fault_pass()
-            n_refreshed = self.scheduler.refresh_pass(self.step_idx)
-            self.obs.on_refresh_pass(n_refreshed, self.step_idx)
-            self._sync_refresh_events()
+            with self.obs.phase_span("refresh"):
+                n_refreshed = self.scheduler.refresh_pass(self.step_idx)
+                self.obs.on_refresh_pass(n_refreshed, self.step_idx)
+                self._sync_refresh_events()
             if self._spec and self.active.any():
-                out = self._step_all_spec()
+                out, rows = self._step_all_spec()
             else:
-                out = self._step_all_decode()
+                out, rows = self._step_all_decode()
+            # rows: active rows in the dispatch; admitted: requests
+            # admitted since the previous step (add_request's included)
+            span.set_metadata(rows=rows, admitted=self._admitted_since_step)
+        self._admitted_since_step = 0
         dt = time.perf_counter() - t0
         self._note_step_time(dt)
         self.obs.on_step_done(self.step_idx, dt)
@@ -810,56 +823,63 @@ class ServeEngine:
             self.obs.sample(self.step_idx, self._obs_sample_payload())
         return out
 
-    def _step_all_decode(self) -> dict:
+    def _step_all_decode(self) -> tuple[dict, int]:
         """The non-speculative decode round: one batched dispatch serves
         every active row (the body `step_all` wraps in scheduling,
-        refresh, fault and observability passes)."""
-        self._ensure_decode_capacity()
-        tokens = np.where(self.active, self.last_token, 0
-                          ).astype(np.int32)[:, None]
-        batch = {"tokens": to_device(tokens),
-                 "positions": to_device(self.positions),
-                 "write_mask": to_device(self.active)}
-        logits = self._dispatch(self._decode, batch)
-        rows = np.flatnonzero(self.active)
-        self._account_dispatch(rows, 1, self.positions[rows] + 1,
-                               self.positions[rows])
-        self.energy_ledger.note_tokens(rows.size)
-        arg = np.asarray(jnp.argmax(logits[:, -1], axis=-1)).astype(np.int32)
-        # vectorized slot bookkeeping: no per-slot Python for the numeric
-        # state, only the per-request output append below
-        act = self.active.copy()
-        if act.any():
-            rows = np.flatnonzero(act)
-            self.store.note_token_writes(rows, self.positions[rows],
-                                         self.step_idx)
-        self.positions[act] += 1
-        self.remaining[act] -= 1
-        self.last_token = np.where(act, arg, self.last_token)
-        done = act & ((self.remaining <= 0)
-                      | (self.positions >= self.max_seq - 1))
-        self.active &= ~done
-        for s in np.flatnonzero(act):
-            rid = self.slot_req[s].id
-            self.outputs[rid].append(int(arg[s]))
-            self.obs.on_tokens(rid, 1, self.step_idx)
-        for s in np.flatnonzero(done):
-            rid = self.slot_req[s].id
-            self.slot_req[s] = None          # release row (cont. batching)
-            self._slot_entry[s] = None
-            self.scheduler.release_row(int(s))
-            self.obs.on_complete(rid, self.step_idx)
-        self.step_idx += 1
-        return {int(s): int(arg[s]) for s in np.flatnonzero(act & ~done)}
+        refresh, fault and observability passes). Returns the step's
+        {row: next_token} and the number of rows it served."""
+        with self.obs.phase_span("capacity"):
+            self._ensure_decode_capacity()
+        with self.obs.phase_span("dispatch"):
+            tokens = np.where(self.active, self.last_token, 0
+                              ).astype(np.int32)[:, None]
+            batch = {"tokens": to_device(tokens),
+                     "positions": to_device(self.positions),
+                     "write_mask": to_device(self.active)}
+            logits = self._dispatch(self._decode, batch)
+            rows = np.flatnonzero(self.active)
+            self._account_dispatch(rows, 1, self.positions[rows] + 1,
+                                   self.positions[rows])
+            self.energy_ledger.note_tokens(rows.size)
+        with self.obs.phase_span("readback"):
+            arg = np.asarray(jnp.argmax(logits[:, -1], axis=-1)
+                             ).astype(np.int32)
+        with self.obs.phase_span("bookkeeping"):
+            # vectorized slot bookkeeping: no per-slot Python for the
+            # numeric state, only the per-request output append below
+            act = self.active.copy()
+            if act.any():
+                self.store.note_token_writes(rows, self.positions[rows],
+                                             self.step_idx)
+            self.positions[act] += 1
+            self.remaining[act] -= 1
+            self.last_token = np.where(act, arg, self.last_token)
+            done = act & ((self.remaining <= 0)
+                          | (self.positions >= self.max_seq - 1))
+            self.active &= ~done
+            for s in rows:
+                rid = self.slot_req[s].id
+                self.outputs[rid].append(int(arg[s]))
+                self.obs.on_tokens(rid, 1, self.step_idx)
+            for s in np.flatnonzero(done):
+                rid = self.slot_req[s].id
+                self.slot_req[s] = None      # release row (cont. batching)
+                self._slot_entry[s] = None
+                self.scheduler.release_row(int(s))
+                self.obs.on_complete(rid, self.step_idx)
+            self.step_idx += 1
+            out = {int(s): int(arg[s]) for s in np.flatnonzero(act & ~done)}
+        return out, int(rows.size)
 
-    def _step_all_spec(self) -> dict:
+    def _step_all_spec(self) -> tuple[dict, int]:
         """One self-speculative round for every active row: spec_k - 1
         cheap draft dispatches propose a spec_k-token window out of the
         dynamic-plane read, ONE full-path verify dispatch scores and
         commits it, and the longest greedily-matching prefix is emitted.
         Greedy accept keeps the emitted stream token-identical to
         step-by-step decode; rejected draft storage is rolled back (page
-        retraction on paged stores, snapshot restore on slab stores)."""
+        retraction on paged stores, snapshot restore on slab stores).
+        Returns the round's {row: last emitted token} and its row count."""
         W = self.spec_k
         B = self.max_batch
         # per-row window cap >= 1: stepwise decode retires a row once its
@@ -870,19 +890,20 @@ class ServeEngine:
         cap[rows] = np.clip(self.max_seq - 1 - self.positions[rows], 1, W)
         # every window slot needs storage BEFORE the draft writes it; the
         # same augment-then-preempt ladder as _ensure_decode_capacity
-        for row in rows:
-            if not self.active[row]:
-                continue    # preempted by an earlier row's allocation
-            while not self.scheduler.ensure_window(
-                    int(row), int(self.positions[row]), int(cap[row]),
-                    self.step_idx):
-                victim = self.scheduler.preemption_victim(int(row),
-                                                          self.active)
-                if victim is None:
-                    raise RuntimeError(
-                        "state store cannot hold one growing sequence — "
-                        "budget_bytes too small for max_seq")
-                self._preempt(victim)
+        with self.obs.phase_span("capacity"):
+            for row in rows:
+                if not self.active[row]:
+                    continue    # preempted by an earlier row's allocation
+                while not self.scheduler.ensure_window(
+                        int(row), int(self.positions[row]), int(cap[row]),
+                        self.step_idx):
+                    victim = self.scheduler.preemption_victim(int(row),
+                                                              self.active)
+                    if victim is None:
+                        raise RuntimeError(
+                            "state store cannot hold one growing sequence"
+                            " — budget_bytes too small for max_seq")
+                    self._preempt(victim)
         rows = np.flatnonzero(self.active)
         wmask2d = self.active[:, None] & (np.arange(W)[None, :]
                                           < cap[:, None])
@@ -907,8 +928,9 @@ class ServeEngine:
                                                     int(rows.size)),
                     "draft")
                 self._spec_stats["draft_dispatches"] += 1
-                toks[:, i + 1] = np.asarray(
-                    jnp.argmax(lg[:, -1], axis=-1)).astype(np.int32)
+                with self.obs.phase_span("readback"):
+                    toks[:, i + 1] = np.asarray(
+                        jnp.argmax(lg[:, -1], axis=-1)).astype(np.int32)
         if self.store.kind == "slab":
             # the verify scan replays the window from the pre-draft state
             self.store.speculative_restore()
@@ -919,13 +941,25 @@ class ServeEngine:
                 {"tokens": to_device(toks),
                  "positions": to_device(self.positions),
                  "write_mask": to_device(wmask2d)})
-        self._spec_stats["verify_dispatches"] += 1
-        self._spec_stats["spec_rounds"] += 1
-        self._account_dispatch(rows, W, self.positions[rows] + cap[rows],
-                               self.positions[rows])
+            self._spec_stats["verify_dispatches"] += 1
+            self._spec_stats["spec_rounds"] += 1
+            self._account_dispatch(rows, W,
+                                   self.positions[rows] + cap[rows],
+                                   self.positions[rows])
         # -- host accept: longest prefix where the verifier agrees with
         # the draft (same formula the verify step committed KV with)
-        v = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+        with self.obs.phase_span("readback"):
+            v = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+        with self.obs.phase_span("bookkeeping"):
+            out = self._spec_accept(rows, toks, v, cap)
+        return out, int(rows.size)
+
+    def _spec_accept(self, rows: np.ndarray, toks: np.ndarray,
+                     v: np.ndarray, cap: np.ndarray) -> dict:
+        """Host accept of one speculative round: emit each row's longest
+        prefix on which the verifier's tokens `v` agree with the draft
+        `toks`, commit or roll back its storage, retire finished rows."""
+        B = self.max_batch
         mism = np.concatenate([toks[:, 1:] != v[:, :-1],
                                np.ones((B, 1), bool)], axis=1)
         n_acc = np.minimum(mism.argmax(axis=1).astype(np.int32) + 1, cap)

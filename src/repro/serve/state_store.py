@@ -1072,16 +1072,23 @@ def make_step_fns(cfg: ModelConfig, store, *,
     fam = cfg.family
 
     if fam in ("dense", "moe", "audio"):
-        return {
-            "decode": lambda p, s, b: M.paged_decode_step(cfg, p, s, b,
-                                                          rules=rules),
-            "prefill": (lambda p, s, b: M.paged_prefill_step(cfg, p, s, b,
-                                                             rules=rules))
-            if fam != "audio" else None,
-            "verify": (lambda p, s, b: M.paged_verify_step(cfg, p, s, b,
-                                                           rules=rules))
-            if fam != "audio" else None,
-        }
+        # named functions: jit names each program after its function
+        # (`jit_decode_step(...)` in a profiler trace, not `jit__lambda`)
+        def decode_step(params, state, batch):
+            return M.paged_decode_step(cfg, params, state, batch,
+                                       rules=rules)
+
+        def prefill_step(params, state, batch):
+            return M.paged_prefill_step(cfg, params, state, batch,
+                                        rules=rules)
+
+        def verify_step(params, state, batch):
+            return M.paged_verify_step(cfg, params, state, batch,
+                                       rules=rules)
+
+        return {"decode": decode_step,
+                "prefill": prefill_step if fam != "audio" else None,
+                "verify": verify_step if fam != "audio" else None}
     if fam == "vlm":
         prefix_bits = store.parts["prefix"].state_bits
 

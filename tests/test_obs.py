@@ -10,9 +10,19 @@ Contracts under test:
   * stats() idempotence — repeated calls return deep-equal payloads
   * zero-overhead disabled mode — the Null facade leaves no state behind
   * per-step wall times surfaced for every run (straggler monitor feed)
+  * profiler annotations — every engine phase lands in a jax.profiler
+    capture as an `engine.<phase>` event nested in its `engine.step`,
+    from the Null facade and the recording one alike, with the same
+    phases the Chrome-JSON plane records; the package itself (and the
+    trace validator) imports without JAX
 """
+import collections
 import dataclasses
+import glob
 import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -138,6 +148,16 @@ def test_validator_catches_malformed_traces():
           {"name": "b", "ph": "i", "s": "t", "ts": 1.0, "pid": 0, "tid": 0}]
     probs = validate_chrome_trace({"traceEvents": ev})
     assert any("monotonic" in p for p in probs)
+
+
+def test_trace_validator_imports_without_jax():
+    """`python -m repro.obs.validate` checks a Chrome-JSON file alone: the
+    package's profiler annotation binds JAX on first use, not on import."""
+    code = ("import sys, repro.obs.validate; "
+            "sys.exit('jax' in sys.modules)")
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)}
+    run = subprocess.run([sys.executable, "-c", code], env=env)
+    assert run.returncode == 0
 
 
 # ---------------------------------------------------------------------------
@@ -378,3 +398,136 @@ def test_engine_prometheus_export(traced_run, tmp_path):
     total = sum(len(v) for v in outs.values())
     assert f"amc_tokens_emitted {total}" in text
     assert 'amc_ttft_s_bucket{le="+Inf"}' in text
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations: the engine's phases in a jax.profiler capture
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = {"admit", "refresh", "capacity", "dispatch", "readback",
+               "bookkeeping"}
+
+
+def _profile(tmp_path, fn):
+    """Run `fn` under a jax.profiler capture; returns its `engine.*` host
+    events as (name, start_ns, end_ns, {stat: value}), by start."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    out = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+           for plane in pd.planes if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("engine.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(e, outer):
+    return outer[1] <= e[1] and e[2] <= outer[2]
+
+
+def _serve_with_queue(cfg, **kw):
+    """Two rows, three requests: two admitted by add_request, the third
+    by a later step's admission pass once a row frees."""
+    eng = ServeEngine(cfg, MESH, max_batch=2, max_seq=48, prefill_chunk=8,
+                      **kw)
+    rows = [eng.add_request(r) for r in _reqs(cfg, 3, 12, 4)]
+    assert rows[:2] == [0, 1] and rows[2] is None
+    while eng.active.any() or eng.scheduler.queue:
+        eng.step_all()
+    return eng
+
+
+@pytest.mark.parametrize("planes", [{}, {"trace": True, "metrics": True}],
+                         ids=["null", "recording"])
+def test_profiler_capture_nests_engine_phases_in_step(tmp_path, planes):
+    cfg = _cfg(kv_mode="int4", pool_mode="always-augmented", **planes)
+    holder = []
+    ev = _profile(tmp_path,
+                  lambda: holder.append(_serve_with_queue(cfg)))
+    eng = holder[0]
+    assert isinstance(eng.obs, NullEngineObs) == (not planes)
+    steps = [e for e in ev if e[0] == "engine.step"]
+    assert len(steps) == eng.step_idx
+    assert [e[3]["step"] for e in steps] == list(range(eng.step_idx))
+    for st in steps:
+        inner = {e[0][len("engine."):] for e in ev
+                 if e is not st and _inside(e, st)}
+        assert STEP_PHASES <= inner, sorted(inner)
+        assert 0 <= st[3]["rows"] <= 2 and st[3]["admitted"] >= 0
+    # the step after add_request's two admissions counts them; the step
+    # whose own pass admits the third request nests its prefill
+    assert steps[0][3]["admitted"] == 2 and steps[0][3]["rows"] == 2
+    late = [s for s in steps[1:] if s[3]["admitted"]]
+    assert len(late) == 1 and late[0][3]["admitted"] == 1
+    admit = [e for e in ev if e[0] == "engine.admit"
+             and _inside(e, late[0])]
+    assert admit[0][3] == {"admitted": 1, "prompt_tokens": 11}
+    for name in ("engine.prefill", "engine.prefill_chunk"):
+        assert any(e[0] == name and _inside(e, admit[0]) for e in ev)
+    chunks = [e[3]["tokens"] for e in ev if e[0] == "engine.prefill_chunk"
+              and _inside(e, admit[0])]
+    assert chunks == [8, 3]
+
+
+def test_add_request_admission_carries_admit_span(tmp_path):
+    cfg = _cfg(kv_mode="int4", pool_mode="always-augmented")
+    eng = ServeEngine(cfg, MESH, max_batch=2, max_seq=48, prefill_chunk=8)
+    reqs = _reqs(cfg, 3, 10, 3)
+    eng.add_request(reqs[0])
+    eng.step_all()                     # compile outside the capture
+    ev = _profile(tmp_path, lambda: [eng.add_request(r) for r in reqs[1:]])
+    admits = [e for e in ev if e[0] == "engine.admit"]
+    assert [a[3] for a in admits] == [
+        {"admitted": 1, "prompt_tokens": 9},
+        {"admitted": 0, "prompt_tokens": 0}]   # no free row: queued
+    assert not any(e[0] == "engine.step" for e in ev)
+    assert any(e[0] == "engine.prefill" and _inside(e, admits[0])
+               and e[3] == {"tokens": 9} for e in ev)
+
+
+def test_spec_and_fault_phases_nest_in_step(tmp_path):
+    cfg = _cfg(kv_mode="int4", pool_mode="always-augmented", spec_k=3,
+               retention_steps=8, fault_rate=0.5, fault_seed=1)
+    eng = ServeEngine(cfg, MESH, max_batch=2, max_seq=64, prefill_chunk=16)
+    ev = _profile(tmp_path, lambda: eng.generate(_reqs(cfg, 3, 20, 8)))
+    steps = [e for e in ev if e[0] == "engine.step"]
+    assert steps and all(s[3]["kind"] == "spec" for s in steps)
+    spec = [s for s in steps if s[3]["rows"]]
+    for st in spec:
+        inner = collections.Counter(e[0] for e in ev
+                                    if e is not st and _inside(e, st))
+        for name in ("admit", "refresh", "capacity", "spec_draft",
+                     "spec_verify", "readback", "bookkeeping",
+                     "fault_pass"):
+            assert inner["engine." + name] >= 1, (name, inner)
+        # one readback per draft token and one after the verify
+        assert inner["engine.readback"] == 3
+    drafts = [e for e in ev if e[0] == "engine.spec_draft"]
+    assert all(any(r[0] == "engine.readback" and _inside(r, d) for r in ev)
+               for d in drafts)
+
+
+def test_chrome_plane_records_the_profiled_phases(tmp_path):
+    """With the Chrome-JSON plane on, each span site writes both sinks:
+    the same phase names, as many times, with the same arguments."""
+    cfg = _cfg(kv_mode="int4", pool_mode="always-augmented",
+               trace=True, metrics=False)
+    holder = []
+    ev = _profile(tmp_path,
+                  lambda: holder.append(_serve_with_queue(cfg)))
+    eng = holder[0]
+    prof = collections.Counter(e[0][len("engine."):] for e in ev)
+    assert set(prof) == STEP_PHASES | {"step", "prefill", "prefill_chunk"}
+    obj = eng.obs.tracer.chrome_trace()
+    spans = [e for e in obj["traceEvents"]
+             if e["ph"] == "X" and e["name"] in prof]
+    assert collections.Counter(e["name"] for e in spans) == prof
+    assert [e["args"] for e in spans if e["name"] == "step"] == [
+        e[3] for e in ev if e[0] == "engine.step"]
+    assert [e["args"] for e in spans if e["name"] == "admit"] == [
+        e[3] for e in ev if e[0] == "engine.admit"]
