@@ -131,11 +131,14 @@ def paged_kv_attention(q, kn, vn, kp, vp, k_scale, v_scale, lengths, modes,
                        interpret=None, use_ref=False):
     """Flash-decode over the paged mode-switchable KV pool.
 
-    Walks each row's page table (scalar-prefetched, hold-previous gather
-    indices so the mode-mismatched arena issues no DMA); per-page mode
-    selects the Normal bf16 plane or the Augmented packed plane. On an
-    all-Augmented pool this is bit-identical to `packed_kv_attention`
-    with bs == page (same block walk, same op order)."""
+    Walks each row's page table (scalar-prefetched) in blocks of
+    `pages_per_block` pages, all KV heads per grid step: each page slab is
+    copied by DMA from the one plane its mode bit names into a
+    double-buffered VMEM block, the next block's copies in flight while
+    the current one computes; blocks past a row's length issue no DMA and
+    no compute. On an all-Augmented pool this is bit-identical to
+    `packed_kv_attention` with bs == the block's tokens (same block walk,
+    same op order), and it is `paged_kv_attention_window` at W = 1."""
     if use_ref:
         # reconstruct the true page table: at mode==1 steps packed_idx
         # holds the real physical page, at mode==0 steps normal_idx does
@@ -158,11 +161,12 @@ def paged_kv_attention_window(q, kn, vn, kp, vp, k_scale, v_scale, starts,
 
     q: (B, KV, W, Hg, D) — the W-token draft window per row at absolute
     positions starts + [0..W); window slot w attends tokens
-    < starts + w + 1 (causal inside the window). Per window slot this is
-    BIT-IDENTICAL to `paged_kv_attention` at lengths == starts + w + 1:
-    the extra pages a slot's shorter horizon masks off contribute
-    exp(-inf) == 0.0 exactly in the f32 online softmax, which is what
-    makes accept/rollback token-identical to step-by-step decode."""
+    < starts + w + 1 (causal inside the window). The same kernel body and
+    block walk as `paged_kv_attention`, so per window slot this is
+    BIT-IDENTICAL to it at lengths == starts + w + 1: the extra tokens a
+    slot's shorter horizon masks off contribute exp(-inf) == 0.0 exactly
+    in the f32 online softmax, which is what makes accept/rollback
+    token-identical to step-by-step decode."""
     if use_ref:
         table = jnp.where(modes == 1, packed_idx, normal_idx)
         return ref.paged_kv_attention_window_ref(
@@ -186,9 +190,9 @@ def paged_prefix_attention(q, kn, vn, kp, vp, k_scale, v_scale, lengths,
     over a page-table band whose valid length is pinned per row
     (`lengths` = prefix tokens, NOT positions + 1). For a single query
     a non-causal read over `lengths` tokens is exactly the causal
-    kernel's masked walk, so the same grid and online softmax are reused
+    kernel's masked walk, so the same walk and online softmax are reused
     — the page tables just come from the store's prefix band. Rows whose
-    prefix is unallocated (length 0) read the write-dump page; callers
+    prefix is unallocated (length 0) read no page and give zeros; callers
     ignore their logits."""
     return paged_kv_attention(q, kn, vn, kp, vp, k_scale, v_scale,
                               lengths, modes, normal_idx, packed_idx,

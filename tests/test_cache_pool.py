@@ -10,6 +10,7 @@ import pytest
 from repro.configs import get_arch
 from repro.configs.base import AMCConfig
 from repro.kernels import ops as K
+from repro.kernels.paged_kv_attention import pages_per_block
 from repro.kernels.ref import rel_err
 from repro.models import layers as L
 from repro.serve.cache_pool import PagedKVPool
@@ -58,8 +59,10 @@ def test_paged_kernel_bit_identical_to_contiguous_on_single_mode(kv_bits):
     q = jnp.asarray(rng.standard_normal((B, KV, Hg, D)), jnp.bfloat16)
     kp_c, vp_c, ks_c, vs_c = _contiguous_packed(rng, B, KV, S, D, kv_bits)
     lengths = jnp.asarray([S, 13], jnp.int32)
-    o_contig = K.packed_kv_attention(q, kp_c, vp_c, ks_c, vs_c, lengths,
-                                     bs=page, kv_bits=kv_bits)
+    o_contig = K.packed_kv_attention(
+        q, kp_c, vp_c, ks_c, vs_c, lengths,
+        bs=page * pages_per_block(page, maxP, KV, Hg, D, kv_bits),
+        kv_bits=kv_bits)
 
     kp, table = _page_out(kp_c, page, maxP, B)
     vp, _ = _page_out(vp_c, page, maxP, B)
@@ -117,6 +120,80 @@ def test_paged_kernel_mixed_mode_matches_oracle(kv_bits):
     o_ref = K.paged_kv_attention(*args, page=page, kv_bits=kv_bits,
                                  use_ref=True)
     assert rel_err(o, o_ref) < 0.02
+
+
+# Multi-block walks: with page 32 a block holds 16 pages (512 tokens), so
+# maxP 20 walks two blocks a row, the second cut short by the table.
+# (KV, Hg, maxP, row lengths, mixed Normal/Augmented pool)
+_WALK_CASES = {
+    "mha": (4, 1, 20, (640, 300), True),
+    "maxp_not_block_multiple": (2, 2, 20, (640, 513), False),
+    "len_1_block_multiple_max_seq": (2, 2, 20, (1, 512, 640), False),
+    "mode_changes_inside_block": (2, 2, 20, (600, 200), True),
+    "table_ends_mid_block": (2, 2, 20, (545, 37), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_kernel_block_walk_matches_oracle(case):
+    """Block walks the small goldens above never take: two blocks a row,
+    a last block that maxP cuts short, rows of one token / exactly one
+    block / max_seq, pages whose mode changes inside a block, and
+    hold-previous tables that end mid-block (entries past a row's pages
+    hold its last page, as `PagedKVPool.device_tables` builds them).
+    Every case must match the gather+dense oracle; on an all-Augmented
+    pool the walk must also be BIT-identical to the contiguous kernel
+    with bs == the block's tokens."""
+    KV, Hg, maxP, lens, mixed = _WALK_CASES[case]
+    rng = np.random.default_rng(3)
+    B, D, page, kv_bits = len(lens), 32, 32, 4
+    pages = pages_per_block(page, maxP, KV, Hg, D, kv_bits, mixed)
+    assert pages < maxP and maxP % pages, "case must walk a short block"
+    S = maxP * page
+    q = jnp.asarray(rng.standard_normal((B, KV, Hg, D)), jnp.bfloat16)
+    kp_c, vp_c, ks_c, vs_c = _contiguous_packed(rng, B, KV, S, D, kv_bits)
+    kp, table = _page_out(kp_c, page, maxP, B)
+    vp, _ = _page_out(vp_c, page, maxP, B)
+    ks, _ = _page_out(ks_c, page, maxP, B)
+    vs, _ = _page_out(vs_c, page, maxP, B)
+    live = np.arange(maxP)[None, :] < -(-np.asarray(lens)[:, None] // page)
+    modes = np.where(live, 1, 0).astype(np.int32)
+    kn = vn = jnp.zeros((1, KV, page, D), jnp.bfloat16)
+    if mixed:
+        modes = np.where(live & (rng.random((B, maxP)) < 0.5), 0, modes)
+        kn = jnp.zeros((B * maxP + 1, KV, page, D), jnp.bfloat16)
+        vn = jnp.zeros((B * maxP + 1, KV, page, D), jnp.bfloat16)
+        for b, p in zip(*np.nonzero(live & (modes == 0))):
+            phys = table[b, p]
+            kn = kn.at[phys].set(L.unpack_kv_int4(kp[phys],
+                                                  ks[phys][..., None]))
+            vn = vn.at[phys].set(L.unpack_kv_int4(vp[phys],
+                                                  vs[phys][..., None]))
+    nidx = np.zeros((B, maxP), np.int32)
+    pidx = np.zeros((B, maxP), np.int32)
+    lastn = np.zeros(B, np.int32)
+    lastp = np.zeros(B, np.int32)
+    for s in range(maxP):
+        lastn = np.where(live[:, s] & (modes[:, s] == 0), table[:, s], lastn)
+        lastp = np.where(live[:, s] & (modes[:, s] == 1), table[:, s], lastp)
+        nidx[:, s], pidx[:, s] = lastn, lastp
+    lengths = jnp.asarray(lens, jnp.int32)
+    args = (q, kn, vn, kp, vp, ks, vs, lengths, jnp.asarray(modes),
+            jnp.asarray(nidx), jnp.asarray(pidx))
+    o = K.paged_kv_attention(*args, page=page, kv_bits=kv_bits)
+    o_ref = K.paged_kv_attention(*args, page=page, kv_bits=kv_bits,
+                                 use_ref=True)
+    assert rel_err(o, o_ref) < 0.02
+    if not mixed:
+        # the contiguous kernel walks whole blocks: pad S to a multiple
+        bs = pages * page
+        pad = ((0, 0), (0, 0), (0, -S % bs))
+        o_contig = K.packed_kv_attention(
+            q, jnp.pad(kp_c, pad + ((0, 0),)), jnp.pad(vp_c, pad + ((0, 0),)),
+            jnp.pad(ks_c, pad), jnp.pad(vs_c, pad), lengths, bs=bs,
+            kv_bits=kv_bits)
+        assert (np.asarray(o).view(np.uint16)
+                == np.asarray(o_contig).view(np.uint16)).all()
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,11 @@
 """The main-path kernels compile with Mosaic for a TPU v5e at granite-3-2b
 widths (head_dim 64, 8 KV heads, 4 query heads per KV head, d_model 2048,
-d_ff 8192). Nothing runs: each kernel is compiled for one chip of a
-described v5e:2x2 topology, and the compiled text must hold the Mosaic
-custom call. The CPU tests run these kernels in the Pallas interpreter,
-which accepts layouts the chip's compiler refuses."""
+d_ff 8192), and the paged decode kernel also at minicpm-2b's MHA heads
+(36 KV heads, one query head each). Nothing runs: each kernel is
+compiled for one chip of a described v5e:2x2 topology, and the compiled
+text must hold the Mosaic custom call. The CPU tests run these kernels
+in the Pallas interpreter, which accepts layouts the chip's compiler
+refuses."""
 import functools
 import os
 
@@ -52,16 +54,17 @@ def _compiled_text(sharding, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged_shapes(page, kv_bits, q_shape):
-    max_p = MAX_SEQ // page
-    n_pages = B * max_p + 1
+def _paged_shapes(page, kv_bits, q_shape, *, b=B, kv=KV, max_seq=MAX_SEQ,
+                  normal_plane=True):
+    max_p = max_seq // page
+    n_pages = b * max_p + 1
     d_store, dt = (D // 2, jnp.uint8) if kv_bits == 4 else (D, jnp.int8)
-    normal = ((n_pages, KV, page, D), jnp.bfloat16)
-    packed = ((n_pages, KV, page, d_store), dt)
-    scale = ((n_pages, KV, page), jnp.bfloat16)
-    table = ((B, max_p), jnp.int32)
+    normal = ((n_pages if normal_plane else 1, kv, page, D), jnp.bfloat16)
+    packed = ((n_pages, kv, page, d_store), dt)
+    scale = ((n_pages, kv, page), jnp.bfloat16)
+    table = ((b, max_p), jnp.int32)
     return [(q_shape, jnp.bfloat16), normal, normal, packed, packed, scale,
-            scale, ((B,), jnp.int32), table, table, table]
+            scale, ((b,), jnp.int32), table, table, table]
 
 
 @pytest.mark.parametrize("page", [16, 32])
@@ -71,6 +74,27 @@ def test_paged_kv_attention_compiles(one_chip, kv_bits, page):
                            kv_bits=kv_bits)
     text = _compiled_text(one_chip, fn,
                           *_paged_shapes(page, kv_bits, (B, KV, HG, D)))
+    assert "tpu_custom_call" in text
+
+
+# the served shapes: both granite-3-2b cells (16 rows, max_seq 2048 and
+# 1024, int4, always-augmented: the Normal arena is the dump page alone)
+# and minicpm-2b's MHA heads (36 KV heads, one query row each)
+_CELL_SHAPES = {
+    "decode": dict(b=16, kv=KV, hg=HG, max_seq=2048, normal_plane=False),
+    "chat": dict(b=16, kv=KV, hg=HG, max_seq=1024, normal_plane=False),
+    "mha": dict(b=16, kv=36, hg=1, max_seq=2048, normal_plane=False),
+    "mha_mixed": dict(b=16, kv=36, hg=1, max_seq=2048, normal_plane=True),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_CELL_SHAPES))
+def test_paged_kv_attention_compiles_at_cell_shapes(one_chip, shape):
+    kw = dict(_CELL_SHAPES[shape])
+    hg = kw.pop("hg")
+    fn = functools.partial(paged_kv_attention_pallas, page=16, kv_bits=4)
+    text = _compiled_text(one_chip, fn, *_paged_shapes(
+        16, 4, (kw["b"], kw["kv"], hg, D), **kw))
     assert "tpu_custom_call" in text
 
 
