@@ -1,5 +1,6 @@
-"""Operations and bytes that a call needs, from its shapes: the numerators
-of the roofline shares and of the model FLOP utilization.
+"""Operations and bytes that a kernel call needs, from its shapes: the
+numerators of the roofline shares. (A model's FLOPs per token, the
+numerator of its FLOP utilization, is its reference module's count.)
 
 Each function counts what the algorithm has to do for the work the call
 serves, whatever kernel implements it: the valid tokens of the rows in
@@ -63,29 +64,6 @@ def dual_plane_matmul(m: int, k: int, n: int) -> tuple[int, int]:
     flops = 2 * m * k * 2 * n
     nbytes = k * n + 2 * n * 4 + m * k * 2 + 2 * m * n * 2
     return flops, nbytes
-
-
-def matmul_params(*, n_layers: int, d_model: int, n_heads: int,
-                  n_kv_heads: int, head_dim: int, d_ff: int,
-                  vocab: int) -> int:
-    """Weights one token multiplies through: attention projections,
-    a SwiGLU MLP per layer, and the logits head over the real vocab."""
-    attn = d_model * (n_heads + 2 * n_kv_heads) * head_dim \
-        + n_heads * head_dim * d_model
-    mlp = 3 * d_model * d_ff
-    return n_layers * (attn + mlp) + d_model * vocab
-
-
-def decode_token_flops(context: int, *, n_layers: int, d_model: int,
-                       n_heads: int, n_kv_heads: int, head_dim: int,
-                       d_ff: int, vocab: int) -> int:
-    """Model FLOPs of one decoded token that attends to `context` tokens:
-    2 per weight it multiplies through, plus 4 * head_dim per query head,
-    layer and attended token."""
-    w = matmul_params(n_layers=n_layers, d_model=d_model, n_heads=n_heads,
-                      n_kv_heads=n_kv_heads, head_dim=head_dim, d_ff=d_ff,
-                      vocab=vocab)
-    return 2 * w + 4 * n_layers * n_heads * head_dim * context
 
 
 def roofline_share(flops: float, nbytes: float, seconds: float,

@@ -1,6 +1,7 @@
 """One run of one cell: build the engine, warm up the cell's programs,
 drive its traffic for the window as the client, check what the window
-served against the plain reference, and make the result line.
+served against the configuration's plain reference (`Cell.reference`),
+and make the result line.
 
 The engine is synchronous, so the harness is the client. Each pass of its
 loop enqueues every request that is due (`ServeEngine.add_request`),
@@ -24,7 +25,6 @@ import numpy as np
 
 import cell as cell_mod
 import counts
-import reference
 import traffic
 
 WARM_ID = 10 ** 9              # request id of the chat warm-up request
@@ -62,33 +62,20 @@ def log(msg: str) -> None:
 # the engine under test
 # ---------------------------------------------------------------------------
 
-CONFIG_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-               "num_attention_heads": "n_heads",
-               "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
-               "intermediate_size": "d_ff", "vocab_size": "vocab",
-               "rope_theta": "rope_theta"}
-
-
-def model_config(conf: dict, arch_override=None):
-    """The repo's ModelConfig for a configuration file, checked against
-    the file: every size the file states is the one that runs."""
+def model_config(c: cell_mod.Cell, arch_override=None):
+    """The repo's ModelConfig for the cell's configuration file, checked
+    by the configuration's reference: the model is one it covers, and
+    every size the file states is the one that runs."""
     import dataclasses as dc
 
     from repro.configs import get_arch
+    conf = c.config
     cfg = arch_override or get_arch(conf["repro_arch"])
     a = conf["amc"]
     cfg = dc.replace(cfg, amc=dc.replace(
         cfg.amc, weight_mode=a["weight_mode"], kv_mode=a["kv_mode"],
         pool_mode=a["pool_mode"], page_size=a["page_size"]))
-    for key, attr in CONFIG_KEYS.items():
-        if key in conf and getattr(cfg, attr) != conf[key]:
-            raise ValueError(f"{conf['repro_arch']}: {key} is "
-                             f"{getattr(cfg, attr)} in the program, "
-                             f"{conf[key]} in the configuration file")
-    if cfg.family != "dense" or cfg.act != "swiglu" \
-            or not cfg.tie_embeddings or cfg.qkv_bias:
-        raise ValueError(f"{cfg.name}: the reference covers the tied "
-                         f"SwiGLU dense decoder only")
+    c.reference.check(cfg, conf)
     return cfg
 
 
@@ -301,20 +288,21 @@ def sample(client: Client, outputs: dict, seed: int, k: int) -> list[int]:
     return pick[:k]
 
 
-def compare(dims: reference.Dims, seed: int, picked: dict, *,
+def compare(ref, dims, seed: int, picked: dict, *,
             control: bool = False) -> dict:
     """Widest gap, over every served token of the picked requests, by
     which the reference's logit of the served token lies below the
-    reference's best. With `control`, also the same reading of the
-    reference computed a precision step lower (reference.py), read at
-    the same positions: the reading a sound limit has to fail."""
-    w = reference.make_weights(dims, seed)
+    reference's best; `ref` is the configuration's reference module and
+    `dims` its `Dims`. With `control`, also the same reading of the
+    reference computed a precision step lower (`served_gaps(...,
+    control=True)`), read at the same positions: the reading a sound
+    limit has to fail."""
+    w = ref.make_weights(dims, seed)
     gaps, ctl, n = [], [], 0
     for prompt, served in picked.values():
-        gaps.append(float(reference.served_gaps(dims, w, prompt,
-                                                served).max()))
+        gaps.append(float(ref.served_gaps(dims, w, prompt, served).max()))
         if control:
-            ctl.append(float(reference.served_gaps(
+            ctl.append(float(ref.served_gaps(
                 dims, w, prompt, served, control=True).max()))
         n += len(served)
     del w
@@ -355,11 +343,9 @@ def run(c: cell_mod.Cell, *, seed: int, seconds: float, trace: bool,
     log(f"setup: {jax.device_count()} x {dev.device_kind} "
         f"({dev.platform}); compile cache {cache_dir}")
 
-    cfg = model_config(c.config, arch_override)
-    dims = reference.Dims.from_config(c.config) if arch_override is None \
-        else reference.Dims(cfg.n_layers, cfg.d_model, cfg.n_heads,
-                            cfg.n_kv_heads, cfg.hd, cfg.d_ff, cfg.vocab,
-                            cfg.rope_theta, cfg.norm_eps)
+    cfg = model_config(c, arch_override)
+    dims = c.reference.Dims.from_config(c.config) if arch_override is None \
+        else c.reference.Dims.from_arch(cfg)
     shape = c.shape
     backlog = c.traffic["arrivals"]["kind"] == "backlog"
     t_b = time.perf_counter()
@@ -437,7 +423,7 @@ def run(c: cell_mod.Cell, *, seed: int, seconds: float, trace: bool,
     gc.unfreeze()
     gc.collect()
     t_r = time.perf_counter()
-    check = compare(dims, seed, picked, control=control)
+    check = compare(c.reference, dims, seed, picked, control=control)
     limit = shape["limits"]["max_logit_gap"]
     correct = judge(check["max_logit_gap"], limit, failed)
     log(f"check: {len(picked)} requests, {check['tokens_compared']} served "
@@ -484,13 +470,18 @@ def run(c: cell_mod.Cell, *, seed: int, seconds: float, trace: bool,
 class Context:
     """What a per-layer metric's reader may read."""
     cell: cell_mod.Cell
-    dims: reference.Dims
+    dims: object               # the reference's Dims of the model that runs
     client: Client
     window: dict
     trace: object
     t0: float
     t_close: float
     peaks: dict
+
+    @property
+    def reference(self):
+        """The configuration's reference module (`Cell.reference`)."""
+        return self.cell.reference
 
     def note(self, msg: str) -> None:
         log(f"metric: {msg}")

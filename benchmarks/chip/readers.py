@@ -66,22 +66,15 @@ def decode_tokens(ctx) -> list[int]:
     return [n for s in ctx.steps_in_window() for n in s.contexts]
 
 
-def dims_kw(ctx) -> dict:
-    m = ctx.dims
-    return dict(n_layers=m.n_layers, d_model=m.d_model, n_heads=m.n_heads,
-                n_kv_heads=m.n_kv_heads, head_dim=m.head_dim, d_ff=m.d_ff,
-                vocab=m.vocab)
-
-
 def step_mfu(ctx) -> float | None:
-    """Model FLOPs of the tokens served in the window, per second of the
-    window, as a % of the chip's bf16 peak."""
+    """Model FLOPs of the tokens served in the window (the configuration's
+    reference counts them), per second of the window, as a % of the
+    chip's bf16 peak."""
     toks = decode_tokens(ctx)
     secs = ctx.t_close - ctx.t0
     if not toks or secs <= 0:
         return None
-    kw = dims_kw(ctx)
-    flops = sum(counts.decode_token_flops(n, **kw) for n in toks)
+    flops = sum(ctx.reference.decode_token_flops(ctx.dims, n) for n in toks)
     return 100.0 * flops / secs / ctx.peaks["bf16_flops_per_s"]
 
 

@@ -1,6 +1,16 @@
 """Plain reference of the served dense transformer, in float32 jax.numpy,
 independent of the program: it imports nothing of `repro` and takes none
-of its weights, scales or tables.
+of its weights, scales or tables. A configuration file names it under
+`reference` (cell.py), and the harness reaches it only as `Cell.reference`.
+
+Interface (every reference module provides it):
+  Dims.from_config(conf)   sizes from the configuration file
+  Dims.from_arch(cfg)      sizes from the program's ModelConfig (tests)
+  check(cfg, conf)         refuses a model this module does not cover, or
+                           one whose sizes differ from the file's
+  make_weights(dims, seed)
+  served_gaps(dims, w, prompt, served, *, control=False)
+  decode_token_flops(dims, context)   model FLOPs of one decoded token
 
 Semantics (those of the repo's dense decoder, which the configuration
 files list beside the published model's departures):
@@ -62,6 +72,50 @@ class Dims:
                    c["head_dim"], c["intermediate_size"], c["vocab_size"],
                    float(c.get("rope_theta", 10000.0)),
                    float(c.get("assumed", {}).get("rms_norm_eps", 1e-6)))
+
+    @classmethod
+    def from_arch(cls, cfg) -> "Dims":
+        return cls(cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                   cfg.hd, cfg.d_ff, cfg.vocab, cfg.rope_theta, cfg.norm_eps)
+
+
+# configuration file key -> the program's ModelConfig attribute
+CONFIG_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+               "num_attention_heads": "n_heads",
+               "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
+               "intermediate_size": "d_ff", "vocab_size": "vocab",
+               "rope_theta": "rope_theta"}
+
+
+def check(cfg, conf: dict) -> None:
+    """Refuse a program model outside the tied SwiGLU dense decoder, or
+    one whose sizes differ from those the configuration file states."""
+    if cfg.family != "dense" or cfg.act != "swiglu" \
+            or not cfg.tie_embeddings or cfg.qkv_bias:
+        raise ValueError(f"{cfg.name}: the reference covers the tied "
+                         f"SwiGLU dense decoder only")
+    for key, attr in CONFIG_KEYS.items():
+        if key in conf and getattr(cfg, attr) != conf[key]:
+            raise ValueError(f"{conf['repro_arch']}: {key} is "
+                             f"{getattr(cfg, attr)} in the program, "
+                             f"{conf[key]} in the configuration file")
+
+
+def matmul_params(m: Dims) -> int:
+    """Weights one token multiplies through: attention projections,
+    a SwiGLU MLP per layer, and the logits head over the real vocab."""
+    attn = m.d_model * (m.n_heads + 2 * m.n_kv_heads) * m.head_dim \
+        + m.n_heads * m.head_dim * m.d_model
+    mlp = 3 * m.d_model * m.d_ff
+    return m.n_layers * (attn + mlp) + m.d_model * m.vocab
+
+
+def decode_token_flops(m: Dims, context: int) -> int:
+    """Model FLOPs of one decoded token that attends to `context` tokens:
+    2 per weight it multiplies through, plus 4 * head_dim per query head,
+    layer and attended token."""
+    return 2 * matmul_params(m) \
+        + 4 * m.n_layers * m.n_heads * m.head_dim * context
 
 
 def leaves(m: Dims) -> list[tuple[str, tuple, bool]]:

@@ -40,7 +40,7 @@ def rehearse(name: str, topo) -> None:
     # interpret; the described chip compiles them with Mosaic
     ops._auto_interpret = lambda interpret: False
     c = cell.load(name)
-    cfg = harness.model_config(c.config)
+    cfg = harness.model_config(c)
     B, S = c.shape["max_batch"], c.shape["max_seq"]
     mesh = make_local_mesh(topo.devices[:c.chips])
     on_mesh = NamedSharding(mesh, PartitionSpec())

@@ -8,7 +8,7 @@ benchmarks/chip/ that it names (cell.py). The run builds the engine
 through `repro.serve.make_serving` with weights made on the device from
 the seed, warms up the cell's programs, drives its traffic for
 `--seconds`, and compares what the window served with the plain
-reference (reference.py).
+reference that the cell's configuration names (cell.py).
 
 The last line of standard output is one JSON object: `correct`,
 `attempted`, `failed`, `metrics` (the cell's end-to-end metrics, or with

@@ -43,7 +43,7 @@ def main() -> None:
     c = cell.load(args.workload)
     if c.traffic["arrivals"]["kind"] != "poisson":
         sys.exit(f"sweep: {args.workload} has no timed arrivals")
-    cfg = harness.model_config(c.config)
+    cfg = harness.model_config(c)
     eng = harness.build_engine(jax, cfg, c.shape, args.seed, c.chips)
     annotate = jax.profiler.TraceAnnotation
     warm = harness.Client(eng, traffic.Generator(c.traffic, args.seed,

@@ -34,7 +34,7 @@ def record(c, seed: int, out: str) -> None:
     import harness
     import trace_reduce
     import traffic
-    cfg = harness.model_config(c.config)
+    cfg = harness.model_config(c)
     eng = harness.build_engine(jax, cfg, c.shape, seed, c.chips)
     batch = c.shape["max_batch"]
     annotate = jax.profiler.TraceAnnotation

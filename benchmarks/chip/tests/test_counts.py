@@ -1,7 +1,15 @@
-"""Hand-counted operations and bytes of the count functions."""
+"""Hand-counted operations and bytes of the count functions, and the
+model FLOPs per token of granite-3-2b's reference module."""
 import pytest
 
+import cell
 import counts
+
+
+def _granite():
+    """granite-3-2b's reference module and its full published widths."""
+    c = cell.load("granite-3-2b.decode")
+    return c.reference, c.reference.Dims.from_config(c.config)
 
 
 def test_kv_token_bytes():
@@ -36,13 +44,23 @@ def test_dual_plane_matmul():
 
 
 def test_decode_token_flops_granite():
-    kw = dict(n_layers=40, d_model=2048, n_heads=32, n_kv_heads=8,
-              head_dim=64, d_ff=8192, vocab=49155)
+    ref, dims = _granite()
+    assert (dims.n_layers, dims.d_model, dims.n_heads, dims.n_kv_heads,
+            dims.head_dim, dims.d_ff, dims.vocab) == \
+        (40, 2048, 32, 8, 64, 8192, 49155)
     per_layer = 2048 * 2048 + 2 * 2048 * 512 + 2048 * 2048 + 3 * 2048 * 8192
     params = 40 * per_layer + 2048 * 49155
-    assert counts.matmul_params(**kw) == params
-    assert counts.decode_token_flops(1000, **kw) == \
+    assert ref.matmul_params(dims) == params
+    assert ref.decode_token_flops(dims, 1000) == \
         2 * params + 4 * 40 * 32 * 64 * 1000
+
+
+# the integers the count gave when it lived in counts.py
+@pytest.mark.parametrize("context,flops", [
+    (1, 5067059200), (800, 5328875520), (2048, 5737820160)])
+def test_dense_decode_token_flops_unchanged(context, flops):
+    ref, dims = _granite()
+    assert ref.decode_token_flops(dims, context) == flops
 
 
 def test_roofline_share_names_its_bound():

@@ -9,8 +9,9 @@ import shutil
 
 from conftest import BENCH
 
+DENSE = "benchmarks/chip/references/dense_decoder.py"
 TINY_CONFIG = {
-    "repro_arch": "granite-3-2b", "source": "test widths",
+    "repro_arch": "granite-3-2b", "reference": DENSE, "source": "test widths",
     "num_hidden_layers": 2, "hidden_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 32, "intermediate_size": 256,
     "vocab_size": 512, "rope_theta": 10000.0,
@@ -38,38 +39,62 @@ def arch():
     return dataclasses.replace(base, n_kv_heads=2)
 
 
+def add_config(root: str, b: dict, name: str, conf: dict) -> None:
+    """Write configs/<name>.json under `root` and its entry into the
+    BENCHMARK.json object `b`."""
+    with open(os.path.join(root, "benchmarks", "chip", "configs",
+                           name + ".json"), "w") as f:
+        json.dump(conf, f)
+    b["configs"].append({"name": name, "source": "test",
+                         "file": f"benchmarks/chip/configs/{name}.json",
+                         "reduced": [], "why": "test"})
+
+
+def add_workload(root: str, b: dict, config: str, mix: str,
+                 limit: float | None = 0.05) -> str:
+    """Write the cell file of `<config>.<mix>` (the tiny serving shape, the
+    traffic `tiny-<mix>`) and its entries into `b`; returns its name."""
+    name = f"{config}.{mix}"
+    with open(os.path.join(root, "benchmarks", "chip", "cells",
+                           name + ".json"), "w") as f:
+        json.dump({"max_batch": 4, "max_seq": 128, "sample_requests": 3,
+                   "limits": {"max_logit_gap": limit}}, f)
+    b["workloads"].append({"name": name, "config": config,
+                           "traffic": f"tiny-{mix}", "chips": 1,
+                           "why": "test"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "workloads" in m:
+            fam = "decode" if any(w.endswith(".decode")
+                                  for w in m["workloads"]) else "chat"
+            if fam == mix:
+                m["workloads"].append(name)
+    return name
+
+
+def read_bench(root: str) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def write_bench(root: str, b: dict) -> None:
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(b, f)
+
+
 def make(tmp: str, limit: float | None = 0.05) -> str:
     """A benchmark root under `tmp` whose BENCHMARK.json adds the tiny
     cells `tiny.decode` and `tiny.chat`; returns the root."""
     bench = os.path.join(tmp, "benchmarks", "chip")
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
-    with open(os.path.join(os.path.dirname(os.path.dirname(BENCH)),
-                           "BENCHMARK.json")) as f:
-        b = json.load(f)
-    with open(os.path.join(bench, "configs", "tiny.json"), "w") as f:
-        json.dump(TINY_CONFIG, f)
-    b["configs"].append({"name": "tiny", "source": "test",
-                         "file": "benchmarks/chip/configs/tiny.json",
-                         "reduced": [], "why": "test"})
+    b = read_bench(os.path.dirname(os.path.dirname(BENCH)))
+    add_config(tmp, b, "tiny", TINY_CONFIG)
     for mix, spec in TRAFFIC.items():
         with open(os.path.join(bench, "traffic", f"tiny-{mix}.json"),
                   "w") as f:
             json.dump(spec, f)
-        with open(os.path.join(bench, "cells", f"tiny.{mix}.json"),
-                  "w") as f:
-            json.dump({"max_batch": 4, "max_seq": 128, "sample_requests": 3,
-                       "limits": {"max_logit_gap": limit}}, f)
-        b["workloads"].append({"name": f"tiny.{mix}", "config": "tiny",
-                               "traffic": f"tiny-{mix}", "chips": 1,
-                               "why": "test"})
-    for m in b["end_to_end"] + b["per_layer"]:
-        if "workloads" in m:
-            fam = "decode" if any(w.endswith(".decode")
-                                  for w in m["workloads"]) else "chat"
-            m["workloads"].append(f"tiny.{fam}")
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(b, f)
+        add_workload(tmp, b, "tiny", mix, limit)
+    write_bench(tmp, b)
     return tmp
 
 
